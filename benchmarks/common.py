@@ -60,8 +60,10 @@ from repro.models.paper_models import (
 from repro.models.transformer import init_params as tf_init
 from repro.training.optimizer import adam, sgd
 
-# Table 1 of the paper (model + optimizer per dataset); reduced widths for
-# CPU tractability — relative strategy comparisons are preserved.
+# Table 1 of the paper (model + optimizer per dataset).  VGG-16 runs at
+# its Table 1 width at FULL scale and at a quarter width at QUICK and
+# smoke scale (``BenchScale.vgg_width``); relative strategy comparisons
+# are preserved.
 DATASET_SETUP = {
     "mnist":   dict(model="ffn", opt=("sgd", 1e-2)),
     "fmnist":  dict(model="ffn", opt=("sgd", 1e-2)),
@@ -81,6 +83,7 @@ class BenchScale:
     steps_per_epoch: int = 8
     eval_every: int = 3
     eval_n: int = 256
+    vgg_width: float = 0.25      # VGG-16 width multiplier (1.0 = Table 1)
 
 
 # QUICK uses the paper's R≈40/E=5 regime scaled to 30 rounds — below ~20
@@ -93,7 +96,8 @@ QUICK = BenchScale(rounds=30, local_epochs=5, eval_every=5)
 #: BENCH_sweep.json analytics sections record whichever value ran).
 DEFAULT_ARRIVAL_THRESHOLD = 0.5
 FULL = BenchScale(n_train=20000, n_test=2000, rounds=40, local_epochs=5,
-                  batch=32, steps_per_epoch=0, eval_every=4, eval_n=512)
+                  batch=32, steps_per_epoch=0, eval_every=4, eval_n=512,
+                  vgg_width=1.0)
 
 
 def _model_fns(dataset: str, scale: BenchScale, seed: int):
@@ -106,7 +110,8 @@ def _model_fns(dataset: str, scale: BenchScale, seed: int):
         return init, classifier_loss(ffn_apply), classifier_accuracy(ffn_apply), opt
     if kind == "vgg":
         n_classes = 100 if dataset == "cifar100" else 10
-        init = lambda k: vgg_init(k, n_classes=n_classes, width_mult=0.25)
+        init = lambda k: vgg_init(k, n_classes=n_classes,
+                                  width_mult=scale.vgg_width)
         return init, classifier_loss(vgg_apply), classifier_accuracy(vgg_apply), opt
     cfg = gpt2_tinymem_config()
     init = lambda k: tf_init(k, cfg)
@@ -421,6 +426,29 @@ def group_cells(
     return groups
 
 
+def _replicate_inits(inits: List, n_nodes: int):
+    """(E, n, ...) initial params — each experiment's one-node init on
+    all of its n nodes — built on the device in one program, so no
+    per-experiment stacked copy outlives it (2 GB each for VGG-16 at
+    n=33)."""
+    return jax.jit(lambda xs: jax.tree.map(
+        lambda *leaves: jnp.stack([jnp.broadcast_to(x, (n_nodes,) + x.shape)
+                                   for x in leaves]), *xs))(inits)
+
+
+def _experiment_devices(leaf, e: int) -> List[int]:
+    """Ids of the devices holding experiment ``e``'s rows of an
+    (E, ...) result array (one device, or several under a mesh)."""
+    ids = set()
+    for shard in leaf.addressable_shards:
+        rows = shard.index[0]
+        lo = rows.start or 0
+        hi = leaf.shape[0] if rows.stop is None else rows.stop
+        if lo <= e < hi:
+            ids.add(shard.device.id)
+    return sorted(ids)
+
+
 def _pad_cap(leaves: Dict[str, np.ndarray], cap: int) -> Dict[str, np.ndarray]:
     return {
         k: np.pad(v, [(0, 0), (0, cap - v.shape[1])] + [(0, 0)] * (v.ndim - 2))
@@ -606,7 +634,7 @@ def run_sweep_cells(
                     data_counts=batchers[d].data_counts()))
             if cell.seed not in init_cache:
                 init_cache[cell.seed] = init(jax.random.key(cell.seed))
-            p0s.append(stack_params([init_cache[cell.seed]] * n_nodes))
+            p0s.append(init_cache[cell.seed])
             t_iid.append(tbs[d])
             t_ood.append(obs[d])
             metas.append((cell, ood_nodes))
@@ -628,7 +656,8 @@ def run_sweep_cells(
             engine_coeffs = ProgramCoeffs(program, stack_states(states))
         else:
             engine_coeffs = np.stack(coeffs)
-        params0 = jax.tree.map(lambda *xs: jnp.stack(xs), *p0s)
+        params0 = _replicate_inits(p0s, n_nodes)
+        del p0s
         stack_tests = lambda ts: {
             k: jnp.stack([jnp.asarray(t[k]) for t in ts]) for k in ts[0]}
         part_kwargs = {}
@@ -649,7 +678,7 @@ def run_sweep_cells(
             np.asarray(data_idx), stack_tests(t_iid), stack_tests(t_ood),
             batch_size=scale.batch, unroll_eval=unroll_eval,
             mesh=mesh, chunk_rounds=chunk_rounds, analytics=spec,
-            **part_kwargs)
+            donate_params0=True, **part_kwargs)
 
         secs = time.time() - t0
         for e, (i, (cell, ood_nodes)) in enumerate(zip(idxs, metas)):
@@ -657,6 +686,15 @@ def run_sweep_cells(
             summary = propagation_summary(
                 hist, cell.topo.adjacency, ood_nodes,
                 arrival_threshold=arrival_threshold)
+            # per-node metrics of every evaluated round, and the devices
+            # that held this experiment's trained params
+            summary["per_node"] = [
+                {"round": int(m.round),
+                 **{k: np.asarray(getattr(m, k)).tolist()
+                    for k in ("train_loss", "iid_acc", "ood_acc")}}
+                for m in hist]
+            summary["param_devices"] = _experiment_devices(
+                jax.tree.leaves(result.params)[0], e)
             summary.update(
                 dataset=ds, topology=cell.topo.name, strategy=cell.strategy,
                 ood_k=cell.ood_k,

@@ -210,7 +210,8 @@ def main():
                 print("VERIFY_OK", {tag!r}, r["compile_s"], "s")
             """)
             out = subprocess.run([sys.executable, "-c", code],
-                                 env=dict(os.environ, PYTHONPATH="src"),
+                                 env=dict(os.environ, PYTHONPATH="src",
+                                          JAX_PLATFORMS="cpu"),
                                  capture_output=True, text=True, timeout=900)
             ok = "VERIFY_OK" in out.stdout
             print(f"verify {tag}: {'COMPILED' if ok else 'FAILED'}")

@@ -14,6 +14,9 @@ import time
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale runs")
     ap.add_argument("--only", default=None,

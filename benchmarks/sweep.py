@@ -387,6 +387,9 @@ def run_legacy_baseline(cells, scale, log=print) -> List[dict]:
 
 
 def main(argv: Optional[List[str]] = None) -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--preset", default=None,
                     help=f"one of {sorted(PRESETS)}")

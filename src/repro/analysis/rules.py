@@ -41,6 +41,7 @@ __all__ = [
 HOST_CALLBACK_PRIMS: Tuple[str, ...] = (
     "io_callback",
     "debug_callback",
+    "debug_print",
     "pure_callback",
     "outside_call",
 )
@@ -313,8 +314,9 @@ class Donation(Rule):
 class HostSync(Rule):
     """No host callbacks inside the scan body.
 
-    ``io_callback`` / ``debug_callback`` / ``pure_callback`` equations
-    inside the round scan would stall every round on a host round-trip,
+    ``io_callback`` / ``debug_callback`` / ``debug_print`` /
+    ``pure_callback`` equations inside the round scan would stall every
+    round on a host round-trip,
     silently destroying the one-dispatch-per-run design (PR 1).  Scope
     ``"scan_body"`` checks the outermost scan (the whole program when no
     scan exists, so unrolled traces use the same spec).

@@ -25,31 +25,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.mixing import CirculantSchedule
 
 __all__ = [
-    "compat_shard_map",
     "gossip_dense",
     "gossip_sparse",
     "pod_gossip",
     "make_gossip_fn",
 ]
-
-
-def compat_shard_map(fn, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax versions
-    (new: ``jax.shard_map(check_vma=False)``; old:
-    ``jax.experimental.shard_map.shard_map(check_rep=False)``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
-def _axis_size(axis_name: str) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)  # older jax: count the axis members
 
 
 def gossip_dense(params, coeffs_rows: jnp.ndarray, axis_name: str = "data"):
@@ -86,7 +66,7 @@ def _shard_roll(leaf: jnp.ndarray, k: int, n_local: int, axis_name: str) -> jnp.
     block therefore spans at most two source shards, shifted by q and q+1
     where q, r = divmod(k, n_local): one ppermute each + slice-concat.
     """
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     q, r = divmod(k % (n_local * size), n_local)
     a = jax.lax.ppermute(leaf, axis_name, _ring_perm(q, size)) if q else leaf
     if r == 0:
@@ -174,6 +154,7 @@ def make_gossip_fn(
         def fn(params, coeffs):
             return gossip_sparse(params, schedule, coeffs, node_axis)
 
-    mapped = compat_shard_map(
-        fn, mesh, in_specs=(leaf_spec, coeff_spec), out_specs=leaf_spec)
+    mapped = jax.shard_map(
+        fn, mesh=mesh, in_specs=(leaf_spec, coeff_spec), out_specs=leaf_spec,
+        check_vma=False)
     return jax.jit(mapped)

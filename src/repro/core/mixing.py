@@ -47,6 +47,7 @@ tolerance — property-tested in tests/test_mixing.py.
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Sequence, Tuple
 
 import jax
@@ -88,19 +89,28 @@ _ROBUST_BIG = 1e30
 _ROBUST_PAD = 2e30
 
 
+def _precision(acc_dtype):
+    """Full precision for f32 contractions (a TPU's default rounds f32
+    operands to bf16); the default for low-precision ones."""
+    if jnp.dtype(acc_dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return None
+
+
 def _leaf_mix(c: jnp.ndarray, leaf: jnp.ndarray,
               mix_in_float32: bool = True) -> jnp.ndarray:
     """out[i, ...] = Σ_j c[i, j] · leaf[j, ...], preserving leaf dtype.
 
     ``mix_in_float32=True`` (default) accumulates in f32 — aggregation of
     bf16 params in low precision loses knowledge exactly where the paper
-    needs it (small OOD deltas).  False accumulates in the leaf dtype (the
-    low-precision-aggregation ablation,
-    ``DecentralizedConfig(mix_in_float32=False)``).
+    needs it (small OOD deltas) — with full-f32 products: a TPU's default
+    matmul precision would round the f32 operands to bf16.  False
+    accumulates in the leaf dtype (the low-precision-aggregation
+    ablation, ``DecentralizedConfig(mix_in_float32=False)``).
     """
     acc_dtype = jnp.float32 if mix_in_float32 else leaf.dtype
     acc = jnp.tensordot(c.astype(acc_dtype), leaf.astype(acc_dtype),
-                        axes=(1, 0))
+                        axes=(1, 0), precision=_precision(acc_dtype))
     return acc.astype(leaf.dtype)
 
 
@@ -258,49 +268,50 @@ def mix_edges(params, coeffs: jnp.ndarray, nbr_idx: jnp.ndarray,
 # ----------------------------------------------------------------------
 # robust aggregation: coordinate-wise order statistics over neighbours
 # ----------------------------------------------------------------------
-def oddeven_sort_pairs(keys: jnp.ndarray, vals: jnp.ndarray):
-    """Sort ``(keys, vals)`` ascending by ``keys`` along axis 0 with a
-    fixed odd-even transposition network — ``d`` passes of vectorized
-    compare-exchanges over a static length-``d`` leading axis.
+def oddeven_sort_pairs(keys: Sequence[jnp.ndarray],
+                       vals: Sequence[jnp.ndarray]):
+    """Sort the slot lists ``(keys, vals)`` ascending by ``keys`` with a
+    fixed odd-even transposition network — ``d`` passes of elementwise
+    compare-exchanges between neighbouring slots of a static length-``d``
+    list (each slot an equally shaped array).
 
-    The network is stable (equal keys never swap), so its output depends
-    only on the input, not on how many extra passes padding adds — which
-    is what makes the ``dmax``-deep jnp reference and the ``d_pad``-deep
-    Pallas kernel bit-identical.  Callers must pre-sanitize keys to
-    finite values (NaN never satisfies ``lo > hi`` consistently and
-    would oscillate forever); see :func:`robust_combine`.
+    The network is stable (equal keys never swap), so the jnp reference
+    and the Pallas kernel, which run it on differently blocked slots, are
+    bit-identical.  Callers must pre-sanitize keys to finite values (NaN
+    never satisfies ``lo > hi`` consistently and would oscillate
+    forever); see :func:`robust_combine`.
     """
-    d = keys.shape[0]
+    keys, vals = list(keys), list(vals)
+    d = len(keys)
     for p in range(d):
-        start = p % 2
-        npairs = (d - start) // 2
-        if npairs == 0:
-            continue
-        stop = start + 2 * npairs
-        lo_k, hi_k = keys[start:stop:2], keys[start + 1:stop:2]
-        lo_v, hi_v = vals[start:stop:2], vals[start + 1:stop:2]
-        swap = lo_k > hi_k
-        new_lo_k = jnp.where(swap, hi_k, lo_k)
-        new_hi_k = jnp.where(swap, lo_k, hi_k)
-        new_lo_v = jnp.where(swap, hi_v, lo_v)
-        new_hi_v = jnp.where(swap, lo_v, hi_v)
-        merged_k = jnp.stack([new_lo_k, new_hi_k], axis=1).reshape(
-            (2 * npairs,) + keys.shape[1:])
-        merged_v = jnp.stack([new_lo_v, new_hi_v], axis=1).reshape(
-            (2 * npairs,) + vals.shape[1:])
-        keys = jnp.concatenate([keys[:start], merged_k, keys[stop:]], axis=0)
-        vals = jnp.concatenate([vals[:start], merged_v, vals[stop:]], axis=0)
+        for i in range(p % 2, d - 1, 2):
+            swap = keys[i] > keys[i + 1]
+            keys[i], keys[i + 1] = (jnp.where(swap, keys[i + 1], keys[i]),
+                                    jnp.where(swap, keys[i], keys[i + 1]))
+            vals[i], vals[i + 1] = (jnp.where(swap, vals[i + 1], vals[i]),
+                                    jnp.where(swap, vals[i], vals[i + 1]))
     return keys, vals
 
 
-def robust_combine(vals: jnp.ndarray, w: jnp.ndarray,
+def _slot_sum(xs: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    """Σ over slots, in slot order (the order a reduction over axis 0
+    takes)."""
+    acc = jnp.zeros_like(xs[0])
+    for x in xs:
+        acc = acc + x
+    return acc
+
+
+def robust_combine(vals: Sequence[jnp.ndarray], w: Sequence[jnp.ndarray],
                    self_vals: jnp.ndarray, op: str,
                    trim_k: int = 1) -> jnp.ndarray:
     """Coordinate-wise robust aggregate of gathered neighbour rows.
 
-    vals: (d, m, t) — slot d's value for destination row m, coordinate t
-      (gathered from the padded-ELL tables; padding slots carry weight 0).
-    w: (d, m) per-slot mixing weights — a slot participates iff w > 0.
+    vals: d slots, each (m, t) — slot d's value for destination row m,
+      coordinate t (gathered from the padded-ELL tables; padding slots
+      carry weight 0).
+    w: d slots of per-slot mixing weights, each (m, 1) or (m, t) — a
+      slot participates iff w > 0.
     self_vals: (m, t) — each destination's own row (the fallback when
       every slot is trimmed away or the support is empty).
     op: ``"trimmed"`` — drop the ``trim_k`` smallest and largest values
@@ -315,51 +326,57 @@ def robust_combine(vals: jnp.ndarray, w: jnp.ndarray,
     are bit-identical (tests/test_robust_mix.py).
 
     This function is called from inside a Pallas kernel body, so it must
-    stay jnp-only with static shapes (no host control flow on traced
-    values, no cumsum primitives — the rank scan is an unrolled loop).
+    stay jnp-only with static shapes and 2-D slots (no host control flow
+    on traced values, no cumsum, no strided slices or gathers — Mosaic
+    lowers none of them; the rank scan and slot sums are unrolled loops).
     """
     if op not in ("trimmed", "median"):
         raise ValueError(f"robust_combine op {op!r} not in "
                          f"('trimmed', 'median')")
-    d = vals.shape[0]
-    acc_dtype = vals.dtype
-    wv = w[:, :, None]
-    valid = wv > 0
+    d = len(vals)
+    shape = self_vals.shape
+    acc_dtype = self_vals.dtype
     big = jnp.asarray(_ROBUST_BIG, acc_dtype)
-    keys = jnp.clip(jnp.nan_to_num(vals, nan=_ROBUST_BIG, posinf=_ROBUST_BIG,
-                                   neginf=-_ROBUST_BIG), -big, big)
-    keys = jnp.where(valid, keys, jnp.asarray(_ROBUST_PAD, acc_dtype))
-    w3 = jnp.where(valid, wv, jnp.zeros_like(wv)).astype(acc_dtype)
-    w3 = jnp.broadcast_to(w3, keys.shape)
-    keys, w3 = oddeven_sort_pairs(keys, w3)
-    occupied = w3 > 0
-    # unrolled rank scan (no jnp.cumsum — it has no Mosaic lowering)
-    rank = jnp.zeros(keys.shape[1:], jnp.int32)
-    ranks = []
-    for i in range(d):
-        rank = rank + occupied[i].astype(jnp.int32)
-        ranks.append(rank)
-    r_lo = jnp.stack(ranks, axis=0)          # 1-based rank among occupied
+    pad = jnp.full(shape, _ROBUST_PAD, acc_dtype)
+    zero = jnp.zeros(shape, acc_dtype)
+    keys, ws = [], []
+    for v, wi in zip(vals, w):
+        valid = jnp.broadcast_to(wi > 0, shape)
+        key = jnp.clip(jnp.nan_to_num(v, nan=_ROBUST_BIG, posinf=_ROBUST_BIG,
+                                      neginf=-_ROBUST_BIG), -big, big)
+        keys.append(jnp.where(valid, key, pad))
+        ws.append(jnp.where(valid, jnp.broadcast_to(wi, shape).astype(
+            acc_dtype), zero))
+    keys, ws = oddeven_sort_pairs(keys, ws)
+    occupied = [wi > 0 for wi in ws]
+    # unrolled rank scan: 1-based rank of each slot among the occupied
+    rank = jnp.zeros(shape, jnp.int32)
+    r_lo = []
+    for occ in occupied:
+        rank = rank + occ.astype(jnp.int32)
+        r_lo.append(rank)
     cnt = rank                               # occupied slots per (m, t)
     if op == "median":
         lo = (cnt - 1) // 2
         hi = cnt // 2
-        iota = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 0)
-        med = (jnp.sum(jnp.where(iota == lo[None], keys,
-                                 jnp.zeros_like(keys)), axis=0)
-               + jnp.sum(jnp.where(iota == hi[None], keys,
-                                   jnp.zeros_like(keys)), axis=0))
+        med = (_slot_sum([jnp.where(lo == i, keys[i], zero)
+                          for i in range(d)])
+               + _slot_sum([jnp.where(hi == i, keys[i], zero)
+                            for i in range(d)]))
         half = jnp.asarray(0.5, acc_dtype)
         return jnp.where(cnt > 0, half * med, self_vals)
-    r_hi = cnt[None] - r_lo + occupied.astype(jnp.int32)
-    keep = occupied & (r_lo > trim_k) & (r_hi > trim_k)
-    wk = jnp.where(keep, w3, jnp.zeros_like(w3))
-    mass = jnp.sum(wk, axis=0)
-    num = jnp.sum(wk * keys, axis=0)
+    wk = []
+    for i in range(d):
+        r_hi = cnt - r_lo[i] + occupied[i].astype(jnp.int32)
+        keep = occupied[i] & (r_lo[i] > trim_k) & (r_hi > trim_k)
+        wk.append(jnp.where(keep, ws[i], zero))
+    mass = _slot_sum(wk)
+    num = _slot_sum([wi * k for wi, k in zip(wk, keys)])
     safe = jnp.where(mass > 0, mass, jnp.ones_like(mass))
     return jnp.where(mass > 0, num / safe, self_vals)
 
 
+@functools.partial(jax.jit, static_argnames=("op", "trim_k", "mix_in_float32"))
 def mix_robust_tables(params, coeffs: jnp.ndarray, nbr_idx: jnp.ndarray,
                       nbr_mask: jnp.ndarray, op: str, trim_k: int = 1,
                       mix_in_float32: bool = True):
@@ -373,20 +390,23 @@ def mix_robust_tables(params, coeffs: jnp.ndarray, nbr_idx: jnp.ndarray,
     Pallas counterpart is ``repro.kernels.gossip_mix.mix_robust_pallas``
     and the two are bit-identical (same op sequence, see
     :func:`robust_combine`).  O(n·dmax·|leaf|) memory for the gathered
-    value tensor — fine at sweep scale (dmax ≪ n), not a kernel.
+    value slots — fine at sweep scale (dmax ≪ n), not a kernel.
+
+    Always compiled, like the kernel body: under jit XLA may contract the
+    weighted sum into fused multiply-adds (on hosts that have them), so
+    an op-by-op reference would round differently from the kernel.
     """
     idx = jnp.asarray(nbr_idx)
     w = edge_weights(jnp.asarray(coeffs).astype(jnp.float32), idx,
                      jnp.asarray(nbr_mask))
-    n = idx.shape[0]
-    wt = w.T  # (dmax, n) — slot axis leading, like the kernel tables
+    n, d = idx.shape
 
     def leaf_fn(leaf: jnp.ndarray) -> jnp.ndarray:
         acc_dtype = jnp.float32 if mix_in_float32 else leaf.dtype
         flat = leaf.reshape(n, -1).astype(acc_dtype)
-        vals = jnp.take(flat, idx.T, axis=0)          # (dmax, n, p)
-        out = robust_combine(vals, wt.astype(acc_dtype), flat, op,
-                             trim_k=trim_k)
+        vals = [jnp.take(flat, idx[:, k], axis=0) for k in range(d)]
+        ws = [w[:, k:k + 1].astype(acc_dtype) for k in range(d)]
+        out = robust_combine(vals, ws, flat, op, trim_k=trim_k)
         return out.astype(leaf.dtype).reshape(leaf.shape)
 
     return jax.tree.map(leaf_fn, params)

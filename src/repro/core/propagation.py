@@ -32,7 +32,6 @@ __all__ = [
     "propagation_summary",
     "render_propagation_map",
     "hops_from",
-    "trapezoid",
     "UNREACHABLE",
     "NO_ARRIVAL",
 ]
@@ -52,20 +51,6 @@ NO_ARRIVAL = -1
 Sources = Union[int, Sequence[int], np.ndarray]
 
 
-def trapezoid(y: np.ndarray, x: np.ndarray, axis: int = 0) -> np.ndarray:
-    """``np.trapezoid`` with a pre-numpy-2.0 fallback.
-
-    ``pyproject.toml`` declares ``numpy>=1.26`` but ``np.trapezoid`` only
-    exists from numpy 2.0 (1.x spells it ``np.trapz``, which 2.x in turn
-    deprecates) — dispatch at call time so both pins work and the fallback
-    stays testable by deleting the attribute (tests/test_propagation.py).
-    """
-    fn = getattr(np, "trapezoid", None)
-    if fn is None:  # numpy < 2.0
-        fn = np.trapz
-    return fn(y, x=x, axis=axis)
-
-
 def _curves(history: Sequence[RoundMetrics], which: str) -> np.ndarray:
     """(rounds, n) matrix of per-node accuracies."""
     key = {"iid": "iid_acc", "ood": "ood_acc"}[which]
@@ -79,7 +64,7 @@ def per_node_auc(history: Sequence[RoundMetrics], which: str) -> np.ndarray:
     if acc.shape[0] == 1:
         return acc[0]
     rounds = np.array([m.round for m in history], dtype=np.float64)
-    auc = trapezoid(acc, x=rounds, axis=0)
+    auc = np.trapezoid(acc, x=rounds, axis=0)
     return auc / (rounds[-1] - rounds[0])
 
 

@@ -441,8 +441,13 @@ class SweepEngine:
 
     # ------------------------------------------------------------------
     def _eval(self, stacked_params, test_iid, test_ood):
-        iid = jax.vmap(lambda p: self.eval_fn(p, test_iid))(stacked_params)
-        ood = jax.vmap(lambda p: self.eval_fn(p, test_ood))(stacked_params)
+        # node by node: a vmapped eval would hold every node's activations
+        # over the whole test batch at once (n·eval_n samples — GBs for
+        # VGG-16 or GPT-2 at n=33, more than one chip's HBM)
+        iid = jax.lax.map(lambda p: self.eval_fn(p, test_iid),
+                          stacked_params)
+        ood = jax.lax.map(lambda p: self.eval_fn(p, test_ood),
+                          stacked_params)
         return iid, ood
 
     def _experiment_scan(self, bank, batch_size, eval_mask, rounds_idx,
@@ -551,8 +556,6 @@ class SweepEngine:
         wrapper below and by :meth:`traceable` for static analysis."""
         from jax.sharding import PartitionSpec as P
 
-        from repro.core.gossip import compat_shard_map
-
         exp, rep = P(mesh.axis_names[0]), P()
 
         def body(params, opt, coeffs, idx, data_idx, eval_mask, rounds_idx,
@@ -572,11 +575,11 @@ class SweepEngine:
             + (1 if fault is not None else 0) \
             + (1 if analytics is not None else 0) \
             + (3 if keep_history else 0)
-        return compat_shard_map(
-            body, mesh,
+        return jax.shard_map(
+            body, mesh=mesh,
             in_specs=(exp, exp, exp, exp, exp, rep, rep, rep, exp, exp,
                       exp, exp, exp, exp),
-            out_specs=(exp,) * n_out)
+            out_specs=(exp,) * n_out, check_vma=False)
 
     def _make_sharded_fn(self, mesh, batch_size: int,
                          program: Optional[CoeffProgram],
@@ -632,6 +635,7 @@ class SweepEngine:
                      fcarry={}, fault: Optional[FaultSpec] = None,
                      checkpoint_dir: Optional[str] = None,
                      resume: bool = False,
+                     donate_params0: bool = False,
                      ) -> SweepResult:
         """Sharded and/or chunked execution.  Bit-identical to the scanned
         path: padding rows are dropped, each chunk resumes the exact scan
@@ -685,7 +689,7 @@ class SweepEngine:
                                        participation, fault)
             reput = lambda t: put(t, exp_sh)
         else:
-            if donate:
+            if donate and not donate_params0:
                 # chunk 0 would donate the caller's params0 — copy once
                 params0 = jax.tree.map(
                     lambda x: jnp.asarray(x).copy(), params0)
@@ -983,6 +987,7 @@ class SweepEngine:
         fault_seeds=None,           # (E,) or scalar; None → seed+arange(E)
         checkpoint_dir: Optional[str] = None,
         resume: bool = False,
+        donate_params0: bool = False,
     ) -> SweepResult:
         """Run the whole grid.  ``unroll_eval`` overrides the config flag
         (None → use ``config.unroll_eval``).  ``mesh`` (from
@@ -991,6 +996,9 @@ class SweepEngine:
         schedules.  ``donate`` overrides carry donation in the
         chunked/sharded paths (None → :func:`donation_supported`, i.e.
         donate wherever XLA honors it).  All modes are bit-identical.
+        ``donate_params0=True`` hands ``params0`` over: the first chunk
+        donates its buffers (they are deleted) instead of a copy, which
+        saves one params-sized copy of device memory.
 
         ``coeffs`` may be a :class:`repro.core.coeffs.ProgramCoeffs`
         instead of an ``(E, R, n, n)`` stack: the per-round matrices are
@@ -1062,7 +1070,7 @@ class SweepEngine:
                 test_iid, test_ood, batch_size, mesh, chunk_rounds,
                 states, program, acarry, analytics, keep_history, donate,
                 pcarry, participation, fcarry, fault, checkpoint_dir,
-                resume)
+                resume, donate_params0)
 
         rounds_idx = jnp.arange(rounds, dtype=jnp.int32)
         out = self._run_jit(

@@ -19,11 +19,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gossip_mix import default_interpret
 
 __all__ = ["flash_attention_pallas"]
 
@@ -86,8 +89,13 @@ def _kernel(q_ref, k_ref, v_ref, out_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, causal: bool = True, window: int = 0,
                            logit_softcap: float = 0.0,
                            bq: int = 256, bkv: int = 256,
-                           interpret: bool = True):
-    """q: (B, S, H, hd); k/v: (B, S, KV, hd) → (B, S, H, hd)."""
+                           interpret: Optional[bool] = None):
+    """q: (B, S, H, hd); k/v: (B, S, KV, hd) → (B, S, H, hd).
+
+    interpret: None → auto (compiled on TPU/GPU, interpret on CPU).
+    """
+    if interpret is None:
+        interpret = default_interpret()
     b, s, h, hd = q.shape
     kv = k.shape[2]
     groups = h // kv

@@ -38,9 +38,19 @@ Three generations live here:
   ``gossip_mix_pallas`` call, not the n-row × n_leaves fan-out the mix
   actually performs.)
 
-VMEM budget per fused program: ``n_pad²·4`` (coeffs) + ``2·n_pad·bt·b``
-(plane slab + out tile) — ≈ 1 MiB at n=64, bt=2048, f32, far under the
-~16 MiB/core budget; ``bt`` is the knob if n grows.
+VMEM per program: the plane slab and the output tile, each
+``n_pad·bt·b`` and double-buffered, their f32 working copies, plus the
+plane kernel's double-buffered ``n_pad²·4`` coefficient block and as
+much again for Mosaic's working copies of it.
+:func:`_tile_width` picks the widest ``bt`` (≤ 2048) that keeps this
+under ``_VMEM_BUDGET`` — 2048 at n=33, 128 at n=1024 in f32 — and raises
+the kernel's VMEM limit where even ``bt=128`` does not fit.
+
+The edge-list kernels gather neighbour rows with dynamic row reads of
+the plane slab, driven by the flattened neighbour table passed as
+scalar prefetch (SMEM); a row of a packed (bf16) slab is read as its
+aligned sublane block and selected, since Mosaic only takes dynamic row
+offsets on 32-bit data.
 
 Backend selection: ``interpret=None`` (the default) auto-detects — the
 kernels compile for real on TPU/GPU backends and fall back to Pallas
@@ -49,11 +59,12 @@ interpret mode on CPU, so the same call sites work everywhere.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.plane import PlaneLayout
 
@@ -151,26 +162,66 @@ def _plane_kernel(acc_dtype, c_ref, p_ref, o_ref):
     default; the plane dtype under mix_in_float32=False)."""
     c = c_ref[...].astype(acc_dtype)
     p = p_ref[...].astype(acc_dtype)
-    o_ref[...] = jnp.dot(c, p, preferred_element_type=acc_dtype).astype(
-        o_ref.dtype)
+    # full-f32 products under f32 accumulation (a TPU's default matmul
+    # precision would round the f32 operands to bf16)
+    precision = (jax.lax.Precision.HIGHEST
+                 if jnp.dtype(acc_dtype) == jnp.float32 else None)
+    o_ref[...] = jnp.dot(c, p, precision=precision,
+                         preferred_element_type=acc_dtype).astype(o_ref.dtype)
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+#: VMEM bytes a mix program's buffers may take: under the 16 MiB scoped
+#: VMEM default of a v5e TensorCore, leaving room for Mosaic's own
+#: scratch.
+_VMEM_BUDGET = 12 * 2**20
+_MAX_TILE = 2048
+
+
+def _sublanes(dtype) -> int:
+    """Rows per native (sublane × 128) tile: 8 for 32-bit, 16 for bf16."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _tile_width(n_pad: int, p: int, dtype, fixed_bytes: int = 0,
+                lane_bytes: int = 0,
+                bt: Optional[int] = None) -> Tuple[int, Optional[int]]:
+    """``(bt, vmem_limit_bytes)`` for a kernel streaming ``(n_pad, bt)``
+    plane tiles: the widest lane multiple up to ``bt`` (default 2048, and
+    never wider than the padded plane) whose double-buffered input and
+    output tiles, their f32 working copies, ``lane_bytes`` more per lane
+    of tile and ``fixed_bytes`` (e.g. the plane kernel's coefficient
+    block) fit :data:`_VMEM_BUDGET`.  Where
+    even a 128-lane tile does not fit, the tile is 128 and the returned
+    limit raises the kernel's VMEM allowance to what it needs; otherwise
+    the limit is None (the compiler default)."""
+    item = jnp.dtype(dtype).itemsize
+    per_lane = n_pad * (4 * item + 4 + (4 if item < 4 else 0)) + lane_bytes
+    cap = _round_up(min(bt or _MAX_TILE, _round_up(p, 128)), 128)
+    fit = (_VMEM_BUDGET - fixed_bytes) // per_lane // 128 * 128
+    bt = max(128, min(cap, fit))
+    need = fixed_bytes + per_lane * bt
+    if need <= _VMEM_BUDGET:
+        return bt, None
+    return bt, need + _VMEM_BUDGET // 2
+
+
 @functools.partial(jax.jit,
                    static_argnames=("bt", "interpret", "mix_in_float32"))
 def gossip_plane_pallas(plane: jnp.ndarray, coeffs: jnp.ndarray,
-                        bt: int = 2048,
+                        bt: Optional[int] = None,
                         interpret: Optional[bool] = None,
                         mix_in_float32: bool = True) -> jnp.ndarray:
     """``out = coeffs @ plane`` as ONE ``pallas_call``.
 
     plane: (n, P) — all n node-models' parameters, one row each.
     coeffs: (n, n) row-stochastic mixing matrix.
-    bt: plane tile width (grid = ⌈P/bt⌉ programs; each holds the full
-      coefficient block plus one (n, bt) slab in VMEM).
+    bt: widest plane tile to use (grid = ⌈P/bt⌉ programs; each holds the
+      full coefficient block plus one (n, bt) slab in VMEM).  None → 2048;
+      either way narrowed by :func:`_tile_width` to fit VMEM at this n.
     interpret: None → auto (compiled on TPU/GPU, interpret on CPU).
     mix_in_float32: False accumulates in the plane dtype instead of f32
       (the low-precision-aggregation ablation; see
@@ -185,12 +236,14 @@ def gossip_plane_pallas(plane: jnp.ndarray, coeffs: jnp.ndarray,
     # sublane multiple for the plane dtype (f32: 8, bf16: 16); the f32
     # coefficient block is (n_pad, n_pad) which then also satisfies its
     # own 8-row constraint.
-    sub = 16 if plane.dtype == jnp.bfloat16 else 8
-    n_pad = _round_up(n, sub)
-    # clamp bt to the plane width, then to a lane (128) multiple — a
-    # non-multiple tile would pass in interpret mode but fail Mosaic
-    # lowering on the TPU backend the kernel exists for
-    bt = _round_up(min(bt, _round_up(p, 128)), 128)
+    n_pad = _round_up(n, _sublanes(plane.dtype))
+    # a lane (128) multiple: a non-multiple tile would pass in interpret
+    # mode but fail Mosaic lowering on the TPU backend
+    # the coefficient block double-buffered, plus as much again for the
+    # working copies Mosaic makes of it for the full-precision product
+    # (measured: at n=1024 a bf16 plane with bt=256 needs 23 MiB)
+    bt, vmem_limit = _tile_width(n_pad, p, plane.dtype,
+                                 fixed_bytes=4 * n_pad * n_pad * 4, bt=bt)
     p_pad = _round_up(p, bt)
     if (n_pad, p_pad) != (n, p):
         plane = jnp.pad(plane, ((0, n_pad - n), (0, p_pad - p)))
@@ -208,13 +261,14 @@ def gossip_plane_pallas(plane: jnp.ndarray, coeffs: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((n_pad, bt), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, p_pad), plane.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(c, plane)
     return out[:n, :p]
 
 
 def mix_plane_pallas(params, coeffs: jnp.ndarray,
-                     bt: int = 2048,
+                     bt: Optional[int] = None,
                      plane_dtype=None,
                      interpret: Optional[bool] = None,
                      mix_in_float32: bool = True):
@@ -245,28 +299,93 @@ def mix_plane_pallas(params, coeffs: jnp.ndarray,
 # ----------------------------------------------------------------------
 # edge-list segment mix: sparse gather-accumulate over the flat plane
 # ----------------------------------------------------------------------
-def _edges_kernel(acc_dtype, n_rows, w_ref, i_ref, p_ref, o_ref):
-    """One (n_pad, bt) output tile of the edge-list mix.  w_ref / i_ref:
-    (d_pad, n_lane) per-edge weights (f32) and neighbour indices (int32) —
-    transposed so the big n axis sits on lanes; p_ref: (n_pad, bt) plane
-    slab.  The d loop is static (unrolled): step d gathers every
-    destination's d-th neighbour row from the slab and accumulates it
-    under the gathered per-edge weight — a segment-sum over the padded-ELL
-    edge list, O(n·dmax·bt) MACs instead of the dense n²·bt."""
-    slab = p_ref[...].astype(acc_dtype)
-    w = w_ref[...]
-    idx = i_ref[...]
-    acc = jnp.zeros(o_ref.shape, acc_dtype)
-    for d in range(w.shape[0]):  # d_pad is static → unrolled
-        wk = w[d, :n_rows].astype(acc_dtype)[:, None]
-        acc = acc + wk * jnp.take(slab, idx[d, :n_rows], axis=0)
-    o_ref[...] = acc.astype(o_ref.dtype)
+def _row(p_ref, j):
+    """Row ``j`` (traced) of a VMEM plane slab as a (1, bt) value.  Mosaic
+    takes a dynamic row offset only on 32-bit data; a packed (bf16) row
+    is read as its aligned sublane block and selected from it, exactly."""
+    sub = _sublanes(p_ref.dtype)
+    if sub == 8:
+        return p_ref[pl.ds(j, 1), :]
+    base = pl.multiple_of(j // sub * sub, sub)
+    blk = p_ref[pl.ds(base, sub), :]
+    pick = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0) == j - base
+    return jnp.sum(jnp.where(pick, blk, jnp.zeros_like(blk)), axis=0,
+                   keepdims=True)
+
+
+def _row_blocks(o_ref, block_fn):
+    """Fill ``o_ref`` one aligned block of sublane rows at a time:
+    ``block_fn(row0)`` gives the (sub, bt) block of rows row0..row0+sub."""
+    sub = _sublanes(o_ref.dtype)
+
+    def body(b, carry):
+        row0 = pl.multiple_of(b * sub, sub)
+        o_ref[pl.ds(row0, sub), :] = block_fn(row0).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, o_ref.shape[0] // sub, body, 0)
+
+
+def _edges_kernel(acc_dtype, d, i_ref, w_ref, p_ref, o_ref):
+    """One (n_pad, bt) output tile of the edge-list mix.  i_ref / w_ref:
+    the (n_pad·d,) neighbour indices (int32) and per-edge weights (f32),
+    destination-major, in SMEM; p_ref: (n_pad, bt) plane slab.  Each
+    destination row accumulates its d neighbour rows, read from the slab
+    at their table offsets, under their weights — a segment-sum over the
+    padded-ELL edge list, O(n·dmax·bt) MACs instead of the dense n²·bt."""
+    sub, bt = _sublanes(o_ref.dtype), o_ref.shape[1]
+
+    def block(row0):
+        rows = []
+        for r in range(sub):
+            e = (row0 + r) * d
+            acc = jnp.zeros((1, bt), acc_dtype)
+            for k in range(d):  # d is static → unrolled
+                wk = jnp.full((1, bt), w_ref[e + k], jnp.float32)
+                acc = acc + (wk.astype(acc_dtype)
+                             * _row(p_ref, i_ref[e + k]).astype(acc_dtype))
+            rows.append(acc)
+        return jnp.concatenate(rows, axis=0)
+
+    _row_blocks(o_ref, block)
+
+
+def _edge_table_call(kernel, plane, weights, nbr_idx, bt, interpret,
+                     lane_bytes: int = 0):
+    """Run an edge-list ``kernel(i_ref, w_ref, p_ref, o_ref)`` over
+    (n_pad, bt) plane tiles, with the (n, dmax) neighbour indices and
+    weights flattened destination-major into scalar prefetch (padded
+    destination rows gather row 0 under weight 0; they are sliced away)."""
+    if interpret is None:
+        interpret = default_interpret()
+    n, p = plane.shape
+    n_pad = _round_up(n, _sublanes(plane.dtype))
+    bt, vmem_limit = _tile_width(n_pad, p, plane.dtype,
+                                 lane_bytes=lane_bytes, bt=bt)
+    p_pad = _round_up(p, bt)
+    if (n_pad, p_pad) != (n, p):
+        plane = jnp.pad(plane, ((0, n_pad - n), (0, p_pad - p)))
+    rows = ((0, n_pad - n), (0, 0))
+    idx = jnp.pad(jnp.asarray(nbr_idx, jnp.int32), rows).reshape(-1)
+    w = jnp.pad(jnp.asarray(weights, jnp.float32), rows).reshape(-1)
+
+    tile = pl.BlockSpec((n_pad, bt), lambda j, i_ref, w_ref: (0, j))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(p_pad // bt,),
+            in_specs=[tile], out_specs=tile),
+        out_shape=jax.ShapeDtypeStruct((n_pad, p_pad), plane.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )(idx, w, plane)
+    return out[:n, :p]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bt", "interpret", "mix_in_float32"))
 def gossip_edges_pallas(plane: jnp.ndarray, weights: jnp.ndarray,
-                        nbr_idx: jnp.ndarray, bt: int = 2048,
+                        nbr_idx: jnp.ndarray, bt: Optional[int] = None,
                         interpret: Optional[bool] = None,
                         mix_in_float32: bool = True) -> jnp.ndarray:
     """``out[i] = Σ_d weights[i, d] · plane[nbr_idx[i, d]]`` as ONE
@@ -280,52 +399,20 @@ def gossip_edges_pallas(plane: jnp.ndarray, weights: jnp.ndarray,
       (``repro.core.topology.padded_neighbor_tables``; padding = own row).
     bt / interpret / mix_in_float32: as :func:`gossip_plane_pallas`.
 
-    Each grid program streams one (n, bt) plane slab plus the (n, dmax)
-    weight/index tables — O(|E|·P) HBM bytes instead of the dense kernel's
-    O(n²) coefficient re-fetches per tile (``mix_modeled_hbm_bytes``
-    models both; the crossover is 2·dmax < n).  The tables are padded to
-    (⌈dmax/8⌉·8, ⌈n/128⌉·128) and transposed so the lane axis carries n;
-    padded slots gather row 0 under weight 0 and padded output rows are
-    sliced away.
+    Each grid program streams one (n, bt) plane slab; the (n, dmax)
+    weight/index tables sit in SMEM for the whole call — O(|E|·P) HBM
+    bytes instead of the dense kernel's O(n²) coefficient re-fetches per
+    tile (``mix_modeled_hbm_bytes`` models both; the crossover is
+    2·dmax < n).
     """
-    if interpret is None:
-        interpret = default_interpret()
-    n, p = plane.shape
-    dmax = weights.shape[1]
-    sub = 16 if plane.dtype == jnp.bfloat16 else 8
-    n_pad = _round_up(n, sub)
-    bt = _round_up(min(bt, _round_up(p, 128)), 128)
-    p_pad = _round_up(p, bt)
-    if (n_pad, p_pad) != (n, p):
-        plane = jnp.pad(plane, ((0, n_pad - n), (0, p_pad - p)))
-    # tables land in VMEM as (d_pad, n_lane) blocks: sublane (8) on the
-    # small dmax axis, lane (128) on n — a (n, dmax) layout would burn a
-    # full 128-lane tile on dmax ≈ 3 ring graphs
-    d_pad = _round_up(dmax, 8)
-    n_lane = _round_up(n_pad, 128)
-    w = jnp.asarray(weights, jnp.float32).T
-    idx = jnp.asarray(nbr_idx, jnp.int32).T
-    w = jnp.pad(w, ((0, d_pad - dmax), (0, n_lane - n)))
-    idx = jnp.pad(idx, ((0, d_pad - dmax), (0, n_lane - n)))
     acc_dtype = jnp.float32 if mix_in_float32 else plane.dtype
-
-    out = pl.pallas_call(
-        functools.partial(_edges_kernel, acc_dtype, n_pad),
-        grid=(p_pad // bt,),
-        in_specs=[
-            pl.BlockSpec((d_pad, n_lane), lambda j: (0, 0)),  # weights
-            pl.BlockSpec((d_pad, n_lane), lambda j: (0, 0)),  # neighbours
-            pl.BlockSpec((n_pad, bt), lambda j: (0, j)),      # plane slab
-        ],
-        out_specs=pl.BlockSpec((n_pad, bt), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, p_pad), plane.dtype),
-        interpret=interpret,
-    )(w, idx, plane)
-    return out[:n, :p]
+    return _edge_table_call(
+        functools.partial(_edges_kernel, acc_dtype, weights.shape[1]),
+        plane, weights, nbr_idx, bt, interpret)
 
 
 def mix_edges_pallas(params, coeffs: jnp.ndarray, nbr_idx, nbr_mask,
-                     bt: int = 2048,
+                     bt: Optional[int] = None,
                      plane_dtype=None,
                      interpret: Optional[bool] = None,
                      mix_in_float32: bool = True):
@@ -354,31 +441,35 @@ def mix_edges_pallas(params, coeffs: jnp.ndarray, nbr_idx, nbr_mask,
 # ----------------------------------------------------------------------
 # robust edge-list mix: in-register sort network over the neighbour axis
 # ----------------------------------------------------------------------
-def _robust_kernel(op, trim_k, acc_dtype, n_rows, w_ref, i_ref, p_ref,
-                   o_ref):
+def _robust_kernel(op, trim_k, acc_dtype, d, i_ref, w_ref, p_ref, o_ref):
     """One (n_pad, bt) output tile of the robust edge-list mix.  Same
-    operands as :func:`_edges_kernel` — (d_pad, n_lane) weight/index
-    tables, (n_pad, bt) plane slab — but instead of the weighted
-    accumulate, every destination's (d_pad, bt) neighbour slab is
-    gathered into registers and reduced by
-    ``repro.core.mixing.robust_combine``: an odd-even transposition sort
-    over the STATIC d_pad axis followed by the trimmed-mean /
-    coordinate-median selection with weight-mass renormalization.
-    Padding slots (weight 0) sort past every real value and are excluded
-    from the order statistics; the destination's own row is the fallback
-    when everything is trimmed.  VMEM working set is O(d_pad·n_pad·bt)
-    for the sorted pairs — ``bt`` is the knob if d_pad·n grows."""
+    operands as :func:`_edges_kernel` — SMEM index/weight tables, (n_pad,
+    bt) plane slab — but instead of the weighted accumulate, each block
+    of destination rows gathers its d (sub, bt) neighbour slots and
+    reduces them by ``repro.core.mixing.robust_combine``: an odd-even
+    transposition sort over the STATIC d axis followed by the
+    trimmed-mean / coordinate-median selection with weight-mass
+    renormalization.  Slots of weight 0 are excluded from the order
+    statistics; the destination's own row is the fallback when everything
+    is trimmed."""
     from repro.core.mixing import robust_combine
 
-    slab = p_ref[...].astype(acc_dtype)
-    w = w_ref[...]
-    idx = i_ref[...]
-    vals = jnp.stack(
-        [jnp.take(slab, idx[d, :n_rows], axis=0) for d in range(w.shape[0])],
-        axis=0)                                    # (d_pad, n_pad, bt)
-    out = robust_combine(vals, w[:, :n_rows].astype(acc_dtype),
-                         slab[:n_rows], op, trim_k=trim_k)
-    o_ref[...] = out.astype(o_ref.dtype)
+    sub, bt = _sublanes(o_ref.dtype), o_ref.shape[1]
+
+    def block(row0):
+        vals, ws = [], []
+        for k in range(d):  # d is static → unrolled
+            es = [(row0 + r) * d + k for r in range(sub)]
+            vals.append(jnp.concatenate(
+                [_row(p_ref, i_ref[e]) for e in es], axis=0
+            ).astype(acc_dtype))
+            ws.append(jnp.concatenate(
+                [jnp.full((1, bt), w_ref[e], jnp.float32) for e in es],
+                axis=0).astype(acc_dtype))
+        own = p_ref[pl.ds(row0, sub), :].astype(acc_dtype)
+        return robust_combine(vals, ws, own, op, trim_k=trim_k)
+
+    _row_blocks(o_ref, block)
 
 
 @functools.partial(jax.jit,
@@ -394,52 +485,25 @@ def gossip_robust_pallas(plane: jnp.ndarray, weights: jnp.ndarray,
     (DESIGN.md §16).
 
     plane / weights / nbr_idx / interpret / mix_in_float32: exactly as
-    :func:`gossip_edges_pallas` (tables padded to (⌈dmax/8⌉·8,
-    ⌈n/128⌉·128) and transposed; padded slots gather row 0 under weight
-    0, which the robust rule excludes by occupancy rather than by
-    multiplying to zero).
+    :func:`gossip_edges_pallas` (slots of weight 0 are excluded by
+    occupancy rather than by multiplying to zero).
     op / trim_k: the robust rule — see
     ``repro.core.mixing.robust_combine``.
-    bt: plane tile width; smaller than the mean kernels' default because
-    each program holds the (d_pad, n_pad, bt) sorted-pair working set in
-    VMEM, not just one slab.
+    bt: widest plane tile; narrower than the mean kernels' default
+      because each block of rows holds its dmax (sub, bt) sorted pairs
+      in VMEM besides the slab (:func:`_tile_width` narrows it further).
 
     Bit-identical to the masked-sort reference
-    ``repro.core.mixing.mix_robust_tables`` — the sort network is stable,
-    so the table padding this kernel adds cannot change the result
-    (tests/test_robust_mix.py).
+    ``repro.core.mixing.mix_robust_tables`` — the same op sequence over
+    the same slots (tests/test_robust_mix.py).
     """
-    if interpret is None:
-        interpret = default_interpret()
-    n, p = plane.shape
-    dmax = weights.shape[1]
-    sub = 16 if plane.dtype == jnp.bfloat16 else 8
-    n_pad = _round_up(n, sub)
-    bt = _round_up(min(bt, _round_up(p, 128)), 128)
-    p_pad = _round_up(p, bt)
-    if (n_pad, p_pad) != (n, p):
-        plane = jnp.pad(plane, ((0, n_pad - n), (0, p_pad - p)))
-    d_pad = _round_up(dmax, 8)
-    n_lane = _round_up(n_pad, 128)
-    w = jnp.asarray(weights, jnp.float32).T
-    idx = jnp.asarray(nbr_idx, jnp.int32).T
-    w = jnp.pad(w, ((0, d_pad - dmax), (0, n_lane - n)))
-    idx = jnp.pad(idx, ((0, d_pad - dmax), (0, n_lane - n)))
     acc_dtype = jnp.float32 if mix_in_float32 else plane.dtype
-
-    out = pl.pallas_call(
-        functools.partial(_robust_kernel, op, trim_k, acc_dtype, n_pad),
-        grid=(p_pad // bt,),
-        in_specs=[
-            pl.BlockSpec((d_pad, n_lane), lambda j: (0, 0)),  # weights
-            pl.BlockSpec((d_pad, n_lane), lambda j: (0, 0)),  # neighbours
-            pl.BlockSpec((n_pad, bt), lambda j: (0, j)),      # plane slab
-        ],
-        out_specs=pl.BlockSpec((n_pad, bt), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, p_pad), plane.dtype),
-        interpret=interpret,
-    )(w, idx, plane)
-    return out[:n, :p]
+    d = weights.shape[1]
+    # ~8 live f32 values per slot while the sort network runs
+    sort_bytes = 8 * d * _sublanes(plane.dtype) * 4
+    return _edge_table_call(
+        functools.partial(_robust_kernel, op, trim_k, acc_dtype, d),
+        plane, weights, nbr_idx, bt, interpret, lane_bytes=sort_bytes)
 
 
 def mix_robust_pallas(params, coeffs: jnp.ndarray, nbr_idx, nbr_mask,
@@ -496,7 +560,7 @@ def mix_modeled_hbm_bytes(impl: str, n: int, p_floats: int,
       HBM traffic to ``"edges"`` — each neighbour row is still gathered
       exactly once per tile and the sort runs entirely in registers/VMEM
       — so robustness costs compute and VMEM working set
-      (O(d_pad·n·bt) sorted pairs), never extra HBM.  Dominance
+      (O(dmax·n·bt) sorted pairs), never extra HBM.  Dominance
       (robust ≥ edges, and < pallas_plane whenever 2·dmax < n) is pinned
       in tests/test_robust_mix.py.
     * ``"sparse"`` — the circulant ring-offset schedule

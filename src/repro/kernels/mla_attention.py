@@ -21,11 +21,14 @@ Validated against ``ref.mla_attention_ref`` in interpret mode.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gossip_mix import default_interpret
 
 __all__ = ["mla_attention_pallas"]
 
@@ -78,11 +81,14 @@ def _kernel(ql_ref, qr_ref, ck_ref, kr_ref, out_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("bq", "bkv", "interpret"))
 def mla_attention_pallas(q_lat, q_rope, c_kv, k_rope,
                          bq: int = 256, bkv: int = 256,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """q_lat: (B,S,H,r) — queries absorbed into the latent basis;
     q_rope: (B,S,H,dr); c_kv: (B,T,r); k_rope: (B,T,dr).
     Returns latent context (B,S,H,r), causal.
+    interpret: None → auto (compiled on TPU/GPU, interpret on CPU).
     """
+    if interpret is None:
+        interpret = default_interpret()
     b, s, h, r = q_lat.shape
     dr = q_rope.shape[-1]
     t = c_kv.shape[1]

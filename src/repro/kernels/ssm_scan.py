@@ -14,20 +14,25 @@ for one chunk with incoming state S₀ (hd_k × hd_v):
   S_out = diag(P_T) S₀ + Σ_s ((P_T/P_s) ⊙ k_s) v_sᵀ  ← state update (GEMM)
 
 Grid = (B·H, S/chunk): the chunk axis is innermost/sequential so S carries
-in VMEM scratch.  Numerics: cumprods in f32 log-space would be exact; we
-use direct f32 cumprod with chunk=64 which keeps P_T ≥ e^{-64·|log w|} in
-range for the decay regimes RWKV-6 produces (w = exp(-exp(·)) ≈ 0.9–0.999).
+in VMEM scratch.  Numerics: P_t is computed in f32 log-space,
+exp(Σ_{s≤t} log w_s), the cumulative sum as a lower-triangular (T, T)
+matmul at full f32 precision (Mosaic lowers neither cumsum nor cumprod);
+chunk=64 keeps P_T ≥ e^{-64·|log w|} in range for the decay regimes
+RWKV-6 produces (w = exp(-exp(·)) ≈ 0.9–0.999).
 
 Validated against ref.rwkv_scan_ref (sequential scan) in interpret mode.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gossip_mix import default_interpret
 
 __all__ = ["rwkv_scan_pallas"]
 
@@ -48,8 +53,16 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, state_scr,
     u = u_ref[0].astype(jnp.float32)     # (1, hd)
     s0 = state_scr[...]                  # (hd, hd)
 
-    p = jnp.cumprod(w, axis=0)           # inclusive cumprod P_t, (T, hd)
-    p_prev = p / w                       # P_{t-1} (P_0 = 1)
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive cumulative decay P_t = exp(Σ_{s≤t} log w_s), (T, hd)
+    log_w = jnp.log(w)
+    log_p = jax.lax.dot_general(
+        (s_idx <= t_idx).astype(jnp.float32), log_w,
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    p = jnp.exp(log_p)
+    p_prev = jnp.exp(log_p - log_w)      # P_{t-1} (P_0 = 1)
 
     r_dec = r * p_prev                   # r̃_t
     k_dec = k / p                        # k̃_s
@@ -60,8 +73,6 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, state_scr,
     # intra-chunk term with strict lower mask
     a = jax.lax.dot_general(r_dec, k_dec, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (T, T)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     a = jnp.where(s_idx < t_idx, a, 0.0)
     y += jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -69,10 +80,14 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, state_scr,
     y += jnp.sum(r * u * k, axis=-1, keepdims=True) * v
     y_ref[0] = y.astype(y_ref.dtype)
 
-    # state update
-    p_total = p[-1]                                       # (hd,)
-    k_scaled = k * (p_total[None] / p)                    # (T, hd)
-    s_new = s0 * p_total[:, None] + jax.lax.dot_general(
+    # state update: P_T as a row (1, hd_k) and as a column (hd_k, 1)
+    log_p_total = log_p[chunk - 1:chunk]
+    log_p_total_col = jax.lax.dot_general(
+        log_w, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    k_scaled = k * jnp.exp(log_p_total - log_p)           # (T, hd)
+    s_new = s0 * jnp.exp(log_p_total_col) + jax.lax.dot_general(
         k_scaled, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     state_scr[...] = s_new
@@ -84,12 +99,15 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, state_scr,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv_scan_pallas(r, k, v, w, u, state, chunk: int = 64,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """r,k,v,w: (B, S, H, hd); u: (H, hd); state: (B, H, hd, hd) f32.
 
     Returns (y (B,S,H,hd), final_state (B,H,hd,hd) f32).
     S is padded to a chunk multiple with w=1, k=0 (identity steps).
+    interpret: None → auto (compiled on TPU/GPU, interpret on CPU).
     """
+    if interpret is None:
+        interpret = default_interpret()
     b, s, h, hd = r.shape
     ps = (s + chunk - 1) // chunk * chunk
     if ps != s:

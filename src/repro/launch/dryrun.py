@@ -3,6 +3,9 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("REPRO_EXTRA_XLA_FLAGS", "")
 )
+# the forced devices are host (CPU) devices: select that platform, so an
+# analytic dry run never claims an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede every other import (jax locks device count on first init).
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape ×

@@ -20,33 +20,21 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["compat_make_mesh", "make_production_mesh", "make_training_mesh",
+__all__ = ["make_production_mesh", "make_training_mesh",
            "make_sweep_mesh", "POD_DATA", "POD_MODEL"]
 
 POD_DATA = 16
 POD_MODEL = 16
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types across jax versions:
-    ``AxisType`` only exists on newer jax; older releases have no explicit
-    sharding mode, so every axis is already Auto."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-_mesh = compat_make_mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """The assignment's canonical production mesh."""
     shape = (2, POD_DATA, POD_MODEL) if multi_pod else (POD_DATA, POD_MODEL)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_sweep_mesh(n_devices: Optional[int] = None,
@@ -59,7 +47,7 @@ def make_sweep_mesh(n_devices: Optional[int] = None,
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
     """
     n = n_devices or len(jax.devices())
-    return _mesh((n,), (axis_name,))
+    return jax.make_mesh((n,), (axis_name,), axis_types=(AxisType.Auto,))
 
 
 def make_training_mesh(n_nodes: int = 16, *, tp: int = POD_MODEL,
@@ -80,4 +68,6 @@ def make_training_mesh(n_nodes: int = 16, *, tp: int = POD_MODEL,
             f"n_nodes·tp = {n_nodes}·{tp} must divide pod size {chips}")
     fsdp = chips // (n_nodes * tp)
     pods = 2 if multi_pod else 1
-    return _mesh((pods, n_nodes, fsdp, tp), ("pod", "node", "fsdp", "model"))
+    return jax.make_mesh((pods, n_nodes, fsdp, tp),
+                         ("pod", "node", "fsdp", "model"),
+                         axis_types=(AxisType.Auto,) * 4)

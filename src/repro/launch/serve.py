@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import get_config, get_smoke_config
 from repro.models.transformer import init_params
 from repro.serving.scheduler import FleetScheduler, Request
@@ -29,6 +30,7 @@ from repro.training.checkpoint import latest_checkpoint, load_checkpoint
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

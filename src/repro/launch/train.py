@@ -3,9 +3,8 @@
 Runs Alg. 1 at framework scale: every topology node trains its own copy of
 the selected architecture on its local token stream; after each round the
 stacked params are gossip-mixed with the configured topology-aware
-strategy.  On the CPU container this runs the reduced (smoke) configs
-end-to-end; on a real mesh the same driver runs the full configs with the
-shardings from ``repro.sharding`` (pass ``--mesh``).
+strategy.  ``--smoke`` runs the reduced configs end to end (on a CPU
+too); without it the driver runs the full configs.
 
 Example (CPU, the e2e driver of deliverable b):
   PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b --smoke \
@@ -21,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ParallelConfig
 from repro.configs.registry import get_config, get_smoke_config
 from repro.core.strategies import AggregationStrategy, mixing_matrix
@@ -46,6 +46,7 @@ def build_topology_from_args(args, n_nodes):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

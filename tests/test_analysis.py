@@ -147,7 +147,7 @@ class TestNegativeFixtures:
         """One stray float64 under x64 poisons the whole round dtype."""
         state, coeffs = _toy_args()
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             def f64_scan(s, cs):
                 def body(carry, coeff):
                     new = (coeff @ carry
@@ -193,7 +193,7 @@ class TestNegativeFixtures:
         report = analyze(chatty, state, coeffs, rules=_catalog())
         _assert_only_trips(report, "host-sync")
         finding = report.outcome("host-sync").findings[0]
-        assert "debug_callback" in finding.message
+        assert "debug_print" in finding.message
 
 
 # ----------------------------------------------------------------------

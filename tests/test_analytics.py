@@ -180,7 +180,9 @@ def test_prop_auc_dominance(seed, rounds, n):
 @given(seed=st.integers(min_value=0, max_value=2**31 - 1),
        rounds=st.integers(min_value=1, max_value=12),
        eval_every=st.integers(min_value=1, max_value=6),
-       threshold=st.floats(min_value=0.1, max_value=0.9, width=32))
+       # bounds given as exact float32 values, as width=32 requires
+       threshold=st.floats(min_value=float(np.float32(0.1)),
+                           max_value=float(np.float32(0.9)), width=32))
 @settings(max_examples=40, deadline=None)
 def test_prop_stream_equals_host_oracle(seed, rounds, eval_every,
                                         threshold):

@@ -48,16 +48,16 @@ SMALL_DRYRUN = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs.registry import get_smoke_config
     from repro.configs.base import ParallelConfig, InputShape
-    from repro.launch.mesh import compat_make_mesh
     from repro.training.train_step import make_train_step
     from repro.training.optimizer import make_optimizer
     from repro.models.transformer import ForwardOptions, init_params
     from repro.sharding import param_specs, opt_specs_like
 
-    mesh = compat_make_mesh((1, 2, 2, 2), ("pod", "node", "fsdp", "model"))
+    mesh = jax.make_mesh((1, 2, 2, 2), ("pod", "node", "fsdp", "model"),
+                         axis_types=(AxisType.Auto,) * 4)
     cfg = get_smoke_config("stablelm-1.6b")
     pcfg = ParallelConfig(n_nodes=2, microbatch=2, remat=True)
     opt = make_optimizer("adamw", 1e-3)

@@ -272,15 +272,25 @@ def test_robust_aggregation_contains_nan_without_quarantine(grid):
 
     Containment is only guaranteed while each neighbourhood sees at most
     ``trim_k`` faulty rows — on ring(4) that means at most ONE faulty
-    node per round.  The draw is deterministic (FaultSpec.seed + the
-    default per-experiment fseeds), and rate 0.15 realizes 3 single-node
-    fault rounds across the nonzero-rate experiments without ever
-    drawing two at once."""
+    node among each node and its two neighbours per round.  The draw is
+    deterministic (``FaultSpec.seed`` + the default per-experiment
+    fseeds); seed 4 at rate 0.15 realizes 4 single-node fault rounds
+    across the nonzero-rate experiments without ever drawing two in one
+    neighbourhood, and the test asserts exactly that precondition."""
     rates = np.asarray([0.0, 0.15, 0.15], np.float32)
+    spec = FaultSpec(mode="nan", seed=4)
+    adj = np.asarray(grid["topo"].adjacency) + np.eye(N)
+    n_faults = 0
+    for e in range(E):
+        for r in range(ROUNDS):
+            faulty = np.asarray(spec.faulty_mask(
+                rates[e], np.uint32(spec.seed + e), r, N)).astype(int)
+            assert (adj @ faulty).max() <= 1, (e, r, faulty)
+            n_faults += faulty.sum()
+    assert n_faults > 0
     for robust in ["trimmed", "median"]:
         res = _engine(grid, robust=robust).run(
-            *grid["args"], batch_size=8, fault=FaultSpec(mode="nan"),
-            fault_rates=rates)
+            *grid["args"], batch_size=8, fault=spec, fault_rates=rates)
         assert (np.asarray(res.fault["fault_rounds"])[1:] > 0).any()
         for leaf in jax.tree.leaves(res.params):
             assert np.isfinite(np.asarray(leaf)).all(), robust

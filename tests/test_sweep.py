@@ -87,6 +87,26 @@ def _assert_trees_equal(t1, t2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+#: f32 tolerance for programs that XLA compiles differently (scanned vs
+#: unrolled vs the legacy trainer): each may fuse and order its float
+#: operations its own way, so they agree to rounding, not bit-for-bit
+F32_TOL = 1e-5
+
+
+def _assert_hist_close(h1, h2):
+    assert [m.round for m in h1] == [m.round for m in h2]
+    for a, b in zip(h1, h2):
+        for k in ("iid_acc", "ood_acc", "train_loss"):
+            np.testing.assert_allclose(getattr(a, k), getattr(b, k),
+                                       rtol=F32_TOL, atol=F32_TOL)
+
+
+def _assert_trees_close(t1, t2):
+    for a, b in zip(jax.tree.leaves(t1), jax.tree.leaves(t2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=F32_TOL, atol=F32_TOL)
+
+
 def _run_mlp(strategy, cfg, coeffs_fn=None):
     trainer = DecentralizedTrainer(
         ring(N), strategy, sgd(1e-2), _loss_fn, _eval_fn, cfg,
@@ -192,7 +212,7 @@ def mnist_setting():
 
 def test_sweep_grid_matches_legacy_per_experiment(mnist_setting):
     """Strategies × seeds through ONE compiled program == N independent
-    legacy DecentralizedTrainer.run calls, bit-for-bit."""
+    legacy DecentralizedTrainer.run calls, to f32 rounding."""
     loss_fn, acc_fn, init, configs = mnist_setting
     topo = ring(N)
     cfg = DecentralizedConfig(rounds=3, local_epochs=1, eval_every=2)
@@ -224,9 +244,11 @@ def test_sweep_grid_matches_legacy_per_experiment(mnist_setting):
     res_unrolled = engine.run(params0, coeffs, bank, indices, data_idx,
                               stack_tests(1), stack_tests(2), batch_size=8,
                               unroll_eval=True)
-    np.testing.assert_array_equal(res.train_loss, res_unrolled.train_loss)
-    np.testing.assert_array_equal(res.iid_acc, res_unrolled.iid_acc)
-    _assert_trees_equal(res.params, res_unrolled.params)
+    np.testing.assert_allclose(res.train_loss, res_unrolled.train_loss,
+                               rtol=F32_TOL, atol=F32_TOL)
+    np.testing.assert_allclose(res.iid_acc, res_unrolled.iid_acc,
+                               rtol=F32_TOL, atol=F32_TOL)
+    _assert_trees_close(res.params, res_unrolled.params)
 
     for e, (kind, seed) in enumerate(cells):
         nb, tb, ob = configs[seed]
@@ -237,8 +259,8 @@ def test_sweep_grid_matches_legacy_per_experiment(mnist_setting):
             stack_params([init(jax.random.key(seed))] * N),
             lambda r: jax.tree.map(jnp.asarray, nb.round_batches(r)),
             jax.tree.map(jnp.asarray, tb), jax.tree.map(jnp.asarray, ob))
-        _assert_hist_equal(hist, res.history(e))
-        _assert_trees_equal(fp, res.experiment_params(e))
+        _assert_hist_close(hist, res.history(e))
+        _assert_trees_close(fp, res.experiment_params(e))
 
 
 # ----------------------------------------------------------------------
@@ -289,6 +311,25 @@ def test_chunked_rounds_matches_scanned_bitexact(mnist_setting):
     res = engine.run(*args, batch_size=8)
     res_chunked = engine.run(*args, batch_size=8, chunk_rounds=2)
     _assert_results_equal(res_chunked, res)
+
+
+def test_chunked_donated_params0_matches_scanned(mnist_setting):
+    """A donated carry copies the caller's params0 and leaves it intact;
+    donate_params0=True donates those buffers instead (they are deleted),
+    with bit-identical results."""
+    cfg = DecentralizedConfig(rounds=3, local_epochs=1, eval_every=2)
+    engine, args = _mnist_grid(mnist_setting, cfg)
+    res = engine.run(*args, batch_size=8)
+    fresh = lambda: jax.tree.map(jnp.copy, args[0])
+    kept = fresh()
+    _assert_results_equal(engine.run(kept, *args[1:], batch_size=8,
+                                     chunk_rounds=2, donate=True), res)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(kept))
+    handed = fresh()
+    _assert_results_equal(engine.run(handed, *args[1:], batch_size=8,
+                                     chunk_rounds=2, donate=True,
+                                     donate_params0=True), res)
+    assert all(x.is_deleted() for x in jax.tree.leaves(handed))
 
 
 def test_sharded_single_device_mesh_matches_scanned(mnist_setting):
