@@ -22,6 +22,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.coeffs import (
     PROGRAM_KINDS,
@@ -479,8 +480,10 @@ def run_sweep_cells(
     row; per-experiment initial params, mixing-matrix stacks, and test
     batches ride the vmap axis.  Returns one ``run_experiment``-compatible
     summary dict per cell (in input order) with ``secs`` amortized over the
-    group and ``sweep_secs``/``sweep_group_size`` recording the batched
-    wall-clock.
+    group.  Host spans (``jax.profiler.TraceAnnotation``) mark each group's
+    ``repro.sweep.prep`` (data split, batchers, test sets, bank, index
+    schedule, coefficients, inits) and ``repro.sweep.summarize`` (the
+    per-experiment summaries) around the engine's own spans.
 
     ``mesh`` (``repro.launch.mesh.make_sweep_mesh``) shards each group's
     experiment axis across devices; ``chunk_rounds`` scans the round
@@ -536,212 +539,214 @@ def run_sweep_cells(
     rows: List[Optional[Dict]] = [None] * len(cells)
     for (ds, n_nodes, robust), idxs in group_cells(cells).items():
         t0 = time.time()
-        init, loss_fn, acc_fn, opt = _model_fns(ds, scale, cells[idxs[0]].seed)
-        mix_support = None
-        if mix_impl != "einsum" or robust in ("trimmed", "median"):
-            # one static schedule per compiled program: the union of every
-            # cell's neighbourhood mask (adjacency + self loops).  The
-            # order-statistic aggregators need it even on the einsum impl
-            # — their padded-ELL tables are static engine configuration.
-            mix_support = np.eye(n_nodes)
+        with TraceAnnotation("repro.sweep.prep"):
+            init, loss_fn, acc_fn, opt = _model_fns(ds, scale, cells[idxs[0]].seed)
+            mix_support = None
+            if mix_impl != "einsum" or robust in ("trimmed", "median"):
+                # one static schedule per compiled program: the union of every
+                # cell's neighbourhood mask (adjacency + self loops).  The
+                # order-statistic aggregators need it even on the einsum impl
+                # — their padded-ELL tables are static engine configuration.
+                mix_support = np.eye(n_nodes)
+                for i in idxs:
+                    mix_support = np.maximum(
+                        mix_support, np.asarray(cells[i].topo.adjacency))
+            engine = SweepEngine(
+                opt, loss_fn, acc_fn,
+                DecentralizedConfig(rounds=scale.rounds,
+                                    local_epochs=scale.local_epochs,
+                                    eval_every=scale.eval_every,
+                                    mix_impl=mix_impl, robust=robust),
+                mix_support=mix_support)
+
+            # distinct data configurations (seed × OOD node) → bank rows.
+            # Synchronous sweep rounds need ONE step count across the group:
+            # with steps_per_epoch=0 each NodeBatcher would derive its own from
+            # its median node size, so the first batcher's derivation is pinned
+            # for the rest (index schedules must stack to a common S).
+            dconf: Dict[Tuple[int, Tuple[int, ...]], int] = {}
+            batchers, tbs, obs = [], [], []
+            group_steps = scale.steps_per_epoch
             for i in idxs:
-                mix_support = np.maximum(
-                    mix_support, np.asarray(cells[i].topo.adjacency))
-        engine = SweepEngine(
-            opt, loss_fn, acc_fn,
-            DecentralizedConfig(rounds=scale.rounds,
-                                local_epochs=scale.local_epochs,
-                                eval_every=scale.eval_every,
-                                mix_impl=mix_impl, robust=robust),
-            mix_support=mix_support)
+                cell = cells[i]
+                ood_nodes = cell.ood_nodes()
+                key = (cell.seed, ood_nodes)
+                if key not in dconf:
+                    train, test = _data(ds, scale.n_train, scale.n_test, cell.seed)
+                    parts = node_datasets(train, n_nodes, ood_node=ood_nodes,
+                                          q=0.10, seed=cell.seed,
+                                          alpha_l=alpha_l, alpha_s=alpha_s)
+                    nb = NodeBatcher(parts, batch_size=scale.batch,
+                                     steps_per_epoch=group_steps,
+                                     seed=cell.seed,
+                                     local_epochs=scale.local_epochs)
+                    group_steps = nb.steps
+                    dconf[key] = len(batchers)
+                    batchers.append(nb)
+                    tbs.append(make_test_batch(test, scale.eval_n, seed=cell.seed))
+                    obs.append(make_test_batch(
+                        backdoored_testset(test, seed=cell.seed), scale.eval_n,
+                        seed=cell.seed, ood_mask=(test.kind == "lm")))
 
-        # distinct data configurations (seed × OOD node) → bank rows.
-        # Synchronous sweep rounds need ONE step count across the group:
-        # with steps_per_epoch=0 each NodeBatcher would derive its own from
-        # its median node size, so the first batcher's derivation is pinned
-        # for the rest (index schedules must stack to a common S).
-        dconf: Dict[Tuple[int, Tuple[int, ...]], int] = {}
-        batchers, tbs, obs = [], [], []
-        group_steps = scale.steps_per_epoch
-        for i in idxs:
-            cell = cells[i]
-            ood_nodes = cell.ood_nodes()
-            key = (cell.seed, ood_nodes)
-            if key not in dconf:
-                train, test = _data(ds, scale.n_train, scale.n_test, cell.seed)
-                parts = node_datasets(train, n_nodes, ood_node=ood_nodes,
-                                      q=0.10, seed=cell.seed,
-                                      alpha_l=alpha_l, alpha_s=alpha_s)
-                nb = NodeBatcher(parts, batch_size=scale.batch,
-                                 steps_per_epoch=group_steps,
-                                 seed=cell.seed,
-                                 local_epochs=scale.local_epochs)
-                group_steps = nb.steps
-                dconf[key] = len(batchers)
-                batchers.append(nb)
-                tbs.append(make_test_batch(test, scale.eval_n, seed=cell.seed))
-                obs.append(make_test_batch(
-                    backdoored_testset(test, seed=cell.seed), scale.eval_n,
-                    seed=cell.seed, ood_mask=(test.kind == "lm")))
+            # D-stacked bank + index schedules (pad node caps to the group max)
+            raw_banks = [nb.sample_bank() for nb in batchers]
+            cap = max(b[next(iter(b))].shape[1] for b in raw_banks)
+            padded = [_pad_cap(b, cap) for b in raw_banks]
+            bank = {k: np.stack([p[k] for p in padded]) for k in raw_banks[0]}
+            indices = np.stack(
+                [nb.all_round_indices(scale.rounds) for nb in batchers])
 
-        # D-stacked bank + index schedules (pad node caps to the group max)
-        raw_banks = [nb.sample_bank() for nb in batchers]
-        cap = max(b[next(iter(b))].shape[1] for b in raw_banks)
-        padded = [_pad_cap(b, cap) for b in raw_banks]
-        bank = {k: np.stack([p[k] for p in padded]) for k in raw_banks[0]}
-        indices = np.stack(
-            [nb.all_round_indices(scale.rounds) for nb in batchers])
-
-        # per-experiment axes.  Every program-supported cell (incl. all
-        # link-failure / reactive cells) goes through its coefficient
-        # program — materialized to a slab in "stack" mode, shipped as
-        # compact state in "program" mode; both consume identical values.
-        reactives = {cells[i].reactive for i in idxs}
-        if coeff_mode == "program" and len(reactives) > 1:
-            raise ValueError(
-                "cells compiled into one program-mode sweep group must "
-                "share the `reactive` flag (it is static program "
-                "configuration); stack mode materializes per-cell "
-                "programs and supports mixed grids")
-        data_idx, coeffs, states, p0s, t_iid, t_ood, metas = (
-            [], [], [], [], [], [], [])
-        program = None
-        init_cache: Dict[int, object] = {}
-        for i in idxs:
-            cell = cells[i]
-            ood_nodes = cell.ood_nodes()
-            d = dconf[(cell.seed, ood_nodes)]
-            data_idx.append(d)
-            strategy = AggregationStrategy(cell.strategy, tau=cell.tau,
-                                           seed=cell.seed)
-            if cell.strategy in PROGRAM_KINDS:
-                program, state = program_for(
-                    cell.topo, strategy,
-                    data_counts=batchers[d].data_counts(),
-                    p_fail=cell.p_fail, reactive=cell.reactive)
-                if coeff_mode == "program":
-                    states.append(state)
+            # per-experiment axes.  Every program-supported cell (incl. all
+            # link-failure / reactive cells) goes through its coefficient
+            # program — materialized to a slab in "stack" mode, shipped as
+            # compact state in "program" mode; both consume identical values.
+            reactives = {cells[i].reactive for i in idxs}
+            if coeff_mode == "program" and len(reactives) > 1:
+                raise ValueError(
+                    "cells compiled into one program-mode sweep group must "
+                    "share the `reactive` flag (it is static program "
+                    "configuration); stack mode materializes per-cell "
+                    "programs and supports mixed grids")
+            data_idx, coeffs, states, p0s, t_iid, t_ood, metas = (
+                [], [], [], [], [], [], [])
+            program = None
+            init_cache: Dict[int, object] = {}
+            for i in idxs:
+                cell = cells[i]
+                ood_nodes = cell.ood_nodes()
+                d = dconf[(cell.seed, ood_nodes)]
+                data_idx.append(d)
+                strategy = AggregationStrategy(cell.strategy, tau=cell.tau,
+                                               seed=cell.seed)
+                if cell.strategy in PROGRAM_KINDS:
+                    program, state = program_for(
+                        cell.topo, strategy,
+                        data_counts=batchers[d].data_counts(),
+                        p_fail=cell.p_fail, reactive=cell.reactive)
+                    if coeff_mode == "program":
+                        states.append(state)
+                    else:
+                        coeffs.append(program.materialize(state, scale.rounds))
                 else:
-                    coeffs.append(program.materialize(state, scale.rounds))
-            else:
-                if coeff_mode == "program" or cell.p_fail or cell.reactive:
-                    raise ValueError(
-                        f"strategy {cell.strategy!r} has no coefficient "
-                        f"program (coeff_mode='program' / link-failure "
-                        f"cells need one); use coeff_mode='stack'")
-                coeffs.append(coeffs_stack(
-                    cell.topo, strategy, scale.rounds,
-                    data_counts=batchers[d].data_counts()))
-            if cell.seed not in init_cache:
-                init_cache[cell.seed] = init(jax.random.key(cell.seed))
-            p0s.append(init_cache[cell.seed])
-            t_iid.append(tbs[d])
-            t_ood.append(obs[d])
-            metas.append((cell, ood_nodes))
+                    if coeff_mode == "program" or cell.p_fail or cell.reactive:
+                        raise ValueError(
+                            f"strategy {cell.strategy!r} has no coefficient "
+                            f"program (coeff_mode='program' / link-failure "
+                            f"cells need one); use coeff_mode='stack'")
+                    coeffs.append(coeffs_stack(
+                        cell.topo, strategy, scale.rounds,
+                        data_counts=batchers[d].data_counts()))
+                if cell.seed not in init_cache:
+                    init_cache[cell.seed] = init(jax.random.key(cell.seed))
+                p0s.append(init_cache[cell.seed])
+                t_iid.append(tbs[d])
+                t_ood.append(obs[d])
+                metas.append((cell, ood_nodes))
 
-        if coeff_mode == "program":
-            # one shared program serves the whole group, so prune its
-            # lax.switch to the UNION of the group's strategy kinds (and
-            # drop the per-round edge mask when no cell churns links):
-            # under vmap-over-E the batched switch computes every traced
-            # branch — for reactive programs the unused 200-iteration
-            # power-method branches were the measured ~1.8× overhead
-            # (BENCH_sweep.json `coeff_programs`).  Bit-identical for the
-            # kinds that remain.
-            program = dataclasses.replace(
-                program,
-                kinds=tuple(sorted({PROGRAM_KINDS.index(cells[i].strategy)
-                                    for i in idxs})),
-                link_failure=any(cells[i].p_fail > 0 for i in idxs))
-            engine_coeffs = ProgramCoeffs(program, stack_states(states))
-        else:
-            engine_coeffs = np.stack(coeffs)
-        params0 = _replicate_inits(p0s, n_nodes)
-        del p0s
-        stack_tests = lambda ts: {
-            k: jnp.stack([jnp.asarray(t[k]) for t in ts]) for k in ts[0]}
-        part_kwargs = {}
-        if participation is not None:
-            part_kwargs = dict(
-                participation=participation,
-                participation_rates=np.asarray(
-                    [1.0 if cells[i].participation is None
-                     else cells[i].participation for i in idxs], np.float32))
-        if fault is not None:
-            part_kwargs.update(
-                fault=fault,
-                fault_rates=np.asarray(
-                    [0.0 if cells[i].fault_rate is None
-                     else cells[i].fault_rate for i in idxs], np.float32))
+            if coeff_mode == "program":
+                # one shared program serves the whole group, so prune its
+                # lax.switch to the UNION of the group's strategy kinds (and
+                # drop the per-round edge mask when no cell churns links):
+                # under vmap-over-E the batched switch computes every traced
+                # branch — for reactive programs the unused 200-iteration
+                # power-method branches were the measured ~1.8× overhead
+                # (BENCH_sweep.json `coeff_programs`).  Bit-identical for the
+                # kinds that remain.
+                program = dataclasses.replace(
+                    program,
+                    kinds=tuple(sorted({PROGRAM_KINDS.index(cells[i].strategy)
+                                        for i in idxs})),
+                    link_failure=any(cells[i].p_fail > 0 for i in idxs))
+                engine_coeffs = ProgramCoeffs(program, stack_states(states))
+            else:
+                engine_coeffs = np.stack(coeffs)
+            params0 = _replicate_inits(p0s, n_nodes)
+            del p0s
+            stack_tests = lambda ts: {
+                k: jnp.stack([jnp.asarray(t[k]) for t in ts]) for k in ts[0]}
+            part_kwargs = {}
+            if participation is not None:
+                part_kwargs = dict(
+                    participation=participation,
+                    participation_rates=np.asarray(
+                        [1.0 if cells[i].participation is None
+                         else cells[i].participation for i in idxs], np.float32))
+            if fault is not None:
+                part_kwargs.update(
+                    fault=fault,
+                    fault_rates=np.asarray(
+                        [0.0 if cells[i].fault_rate is None
+                         else cells[i].fault_rate for i in idxs], np.float32))
+            test_iid, test_ood = stack_tests(t_iid), stack_tests(t_ood)
         result = engine.run(
             params0, engine_coeffs, bank, indices,
-            np.asarray(data_idx), stack_tests(t_iid), stack_tests(t_ood),
+            np.asarray(data_idx), test_iid, test_ood,
             batch_size=scale.batch, unroll_eval=unroll_eval,
             mesh=mesh, chunk_rounds=chunk_rounds, analytics=spec,
             donate_params0=True, **part_kwargs)
 
         secs = time.time() - t0
-        for e, (i, (cell, ood_nodes)) in enumerate(zip(idxs, metas)):
-            hist = result.history(e)
-            summary = propagation_summary(
-                hist, cell.topo.adjacency, ood_nodes,
-                arrival_threshold=arrival_threshold)
-            # per-node metrics of every evaluated round, and the devices
-            # that held this experiment's trained params
-            summary["per_node"] = [
-                {"round": int(m.round),
-                 **{k: np.asarray(getattr(m, k)).tolist()
-                    for k in ("train_loss", "iid_acc", "ood_acc")}}
-                for m in hist]
-            summary["param_devices"] = _experiment_devices(
-                jax.tree.leaves(result.params)[0], e)
-            summary.update(
-                dataset=ds, topology=cell.topo.name, strategy=cell.strategy,
-                ood_k=cell.ood_k,
-                ood_node=(ood_nodes[0] if len(ood_nodes) == 1
-                          else list(ood_nodes)),
-                seed=cell.seed,
-                secs=round(secs / len(idxs), 2), sweep_secs=round(secs, 1),
-                sweep_group_size=len(idxs),
-            )
-            if cell.ood_ks:
-                summary["ood_ks"] = list(cell.ood_ks)
-            if result.analytics is not None:
-                stream = {k: v[e] for k, v in result.analytics.items()}
-                a = analytics_summary(stream, cell.topo.adjacency,
-                                      ood_nodes)
-                a["stream_vs_host_max_dev"] = float(max(
-                    np.abs(stream["iid_auc"]
-                           - per_node_auc(hist, "iid")).max(),
-                    np.abs(stream["ood_auc"]
-                           - per_node_auc(hist, "ood")).max()))
-                summary["analytics"] = a
-            if result.participation is not None:
-                part_row = {k: v[e]
-                            for k, v in result.participation.items()}
-                part_stream = (
-                    {k: v[e] for k, v in result.analytics.items()}
-                    if result.analytics is not None else None)
-                summary["participation_rate"] = (
-                    1.0 if cell.participation is None
-                    else cell.participation)
-                summary["participation"] = participation_summary(
-                    part_row, scale.rounds, part_stream)
-            if result.fault is not None:
-                summary["fault_rate"] = (0.0 if cell.fault_rate is None
-                                         else cell.fault_rate)
-                summary["robust"] = cell.robust
-                summary["fault"] = quarantine_summary(
-                    {k: v[e] for k, v in result.fault.items()},
-                    scale.rounds)
-            if cell.p_fail or cell.reactive:
-                summary.update(p_fail=cell.p_fail, reactive=cell.reactive)
-            if cell.sweep is not None:
-                summary["sweep"] = cell.sweep
-            rows[i] = summary
-            if log is not None:
-                log(csv_row(
-                    cell.label, summary["secs"],
-                    f"iid_auc={summary['iid_auc']:.3f};"
-                    f"ood_auc={summary['ood_auc']:.3f}"))
+        with TraceAnnotation("repro.sweep.summarize"):
+            for e, (i, (cell, ood_nodes)) in enumerate(zip(idxs, metas)):
+                hist = result.history(e)
+                summary = propagation_summary(
+                    hist, cell.topo.adjacency, ood_nodes,
+                    arrival_threshold=arrival_threshold)
+                # per-node metrics of every evaluated round, and the devices
+                # that held this experiment's trained params
+                summary["per_node"] = [
+                    {"round": int(m.round),
+                     **{k: np.asarray(getattr(m, k)).tolist()
+                        for k in ("train_loss", "iid_acc", "ood_acc")}}
+                    for m in hist]
+                summary["param_devices"] = _experiment_devices(
+                    jax.tree.leaves(result.params)[0], e)
+                summary.update(
+                    dataset=ds, topology=cell.topo.name, strategy=cell.strategy,
+                    ood_k=cell.ood_k,
+                    ood_node=(ood_nodes[0] if len(ood_nodes) == 1
+                              else list(ood_nodes)),
+                    seed=cell.seed,
+                    secs=round(secs / len(idxs), 2),
+                )
+                if cell.ood_ks:
+                    summary["ood_ks"] = list(cell.ood_ks)
+                if result.analytics is not None:
+                    stream = {k: v[e] for k, v in result.analytics.items()}
+                    a = analytics_summary(stream, cell.topo.adjacency,
+                                          ood_nodes)
+                    a["stream_vs_host_max_dev"] = float(max(
+                        np.abs(stream["iid_auc"]
+                               - per_node_auc(hist, "iid")).max(),
+                        np.abs(stream["ood_auc"]
+                               - per_node_auc(hist, "ood")).max()))
+                    summary["analytics"] = a
+                if result.participation is not None:
+                    part_row = {k: v[e]
+                                for k, v in result.participation.items()}
+                    part_stream = (
+                        {k: v[e] for k, v in result.analytics.items()}
+                        if result.analytics is not None else None)
+                    summary["participation_rate"] = (
+                        1.0 if cell.participation is None
+                        else cell.participation)
+                    summary["participation"] = participation_summary(
+                        part_row, scale.rounds, part_stream)
+                if result.fault is not None:
+                    summary["fault_rate"] = (0.0 if cell.fault_rate is None
+                                             else cell.fault_rate)
+                    summary["robust"] = cell.robust
+                    summary["fault"] = quarantine_summary(
+                        {k: v[e] for k, v in result.fault.items()},
+                        scale.rounds)
+                if cell.p_fail or cell.reactive:
+                    summary.update(p_fail=cell.p_fail, reactive=cell.reactive)
+                if cell.sweep is not None:
+                    summary["sweep"] = cell.sweep
+                rows[i] = summary
+                if log is not None:
+                    log(csv_row(
+                        cell.label, summary["secs"],
+                        f"iid_auc={summary['iid_auc']:.3f};"
+                        f"ood_auc={summary['ood_auc']:.3f}"))
     return rows  # type: ignore[return-value]

@@ -27,6 +27,13 @@ Two execution modes (DESIGN.md §7):
   host memory.
 
 Both modes produce identical histories — asserted in tests/test_sweep.py.
+
+Each part of a round runs under a ``jax.named_scope`` placed around its
+call site, outside any ``vmap``/``cond``, so that its loop ops carry the
+scope too: ``local_train``, ``mix``, ``coeffs`` (in-scan coefficient
+programs), ``eval`` and ``analytics`` (``batch_gather`` in
+``repro.core.sweep``).  A device profile reads them from each op's name
+(``bench/scopes.py``); they change op metadata only, never a value.
 The vmap-over-experiments axis on top of the scanned mode lives in
 ``repro.core.sweep``.
 
@@ -470,9 +477,12 @@ def make_round_fn(loss_fn: Callable, optimizer: Optimizer, local_epochs: int,
                       robust_clip=robust_clip)
 
     def round_fn(stacked_params, stacked_opt, node_batches, coeffs):
-        params, opt, losses = jax.vmap(local_train)(
-            stacked_params, stacked_opt, node_batches)
-        return mix(params, coeffs), opt, losses
+        with jax.named_scope("local_train"):
+            params, opt, losses = jax.vmap(local_train)(
+                stacked_params, stacked_opt, node_batches)
+        with jax.named_scope("mix"):
+            params = mix(params, coeffs)
+        return params, opt, losses
 
     return round_fn
 
@@ -564,12 +574,14 @@ def make_participation_round_fn(loss_fn: Callable, optimizer: Optimizer,
         steps = jax.tree.leaves(node_batches)[0].shape[1]
         active = participation.active_mask(
             pcarry["rate"], pcarry["pseed"], round_idx, n)
-        trained, opt_t, losses = jax.vmap(local_train)(
-            stacked_params, stacked_opt, node_batches)
+        with jax.named_scope("local_train"):
+            trained, opt_t, losses = jax.vmap(local_train)(
+                stacked_params, stacked_opt, node_batches)
         pub = select(active, trained, pcarry["pub"])
         if not participation.stale_mixing:
             coeffs = participation_renormalize(coeffs, active)
-        mixed = mix(pub, coeffs)
+        with jax.named_scope("mix"):
+            mixed = mix(pub, coeffs)
         params = select(active, mixed, stacked_params)
         opt = select(active, opt_t, stacked_opt)
         losses = jnp.where(active, losses, jnp.zeros((), losses.dtype))
@@ -708,8 +720,9 @@ def make_fault_round_fn(loss_fn: Callable, optimizer: Optimizer,
             pcarry = None
             fcarry, node_batches, coeffs, round_idx = state_and_xs
         n = jax.tree.leaves(stacked_params)[0].shape[0]
-        trained, opt_t, losses = jax.vmap(local_train)(
-            stacked_params, stacked_opt, node_batches)
+        with jax.named_scope("local_train"):
+            trained, opt_t, losses = jax.vmap(local_train)(
+                stacked_params, stacked_opt, node_batches)
         if participation is not None:
             steps = jax.tree.leaves(node_batches)[0].shape[1]
             active = participation.active_mask(
@@ -768,7 +781,8 @@ def make_fault_round_fn(loss_fn: Callable, optimizer: Optimizer,
         else:
             pub_mix = pub
             keep_local = faulty
-        mixed = mix(pub_mix, coeffs)
+        with jax.named_scope("mix"):
+            mixed = mix(pub_mix, coeffs)
         params = select(keep_local, trained, mixed)
         opt = opt_t
         if participation is not None:
@@ -887,7 +901,8 @@ def make_scan_fn(round_fn: Callable, evaluate: Callable,
             else:
                 bx, c, do_eval = xs
             if coeff_fn is not None:
-                c = coeff_fn(c)  # c is this step's absolute round index
+                with jax.named_scope("coeffs"):
+                    c = coeff_fn(c)  # c is this step's absolute round index
             if fault is not None:
                 if participation is not None:
                     p, o, pc, fc, losses = round_fn(
@@ -900,18 +915,21 @@ def make_scan_fn(round_fn: Callable, evaluate: Callable,
             else:
                 p, o, pc, losses = round_fn(p, o, pc, make_batch(bx), c,
                                             r_abs)
-            iid, ood = jax.lax.cond(
-                do_eval,
-                lambda q: evaluate(q, test_iid, test_ood),
-                lambda q: (jnp.zeros((n,)), jnp.zeros((n,))),
-                p)
+            with jax.named_scope("eval"):
+                iid, ood = jax.lax.cond(
+                    do_eval,
+                    lambda q: evaluate(q, test_iid, test_ood),
+                    lambda q: (jnp.zeros((n,)), jnp.zeros((n,))),
+                    p)
             out = [p, o]
             if participation is not None:
                 out.append(pc)
             if fault is not None:
                 out.append(fc)
             if analytics is not None:
-                out.append(analytics.update(ac, r_abs, do_eval, iid, ood))
+                with jax.named_scope("analytics"):
+                    out.append(analytics.update(ac, r_abs, do_eval, iid,
+                                                ood))
             ys = ((losses, iid, ood)
                   if (keep_history or analytics is None) else None)
             return tuple(out), ys
@@ -1018,8 +1036,11 @@ class DecentralizedTrainer:
 
     # ------------------------------------------------------------------
     def _evaluate_impl(self, stacked_params, test_iid, test_ood):
-        iid = jax.vmap(lambda p: self.eval_fn(p, test_iid))(stacked_params)
-        ood = jax.vmap(lambda p: self.eval_fn(p, test_ood))(stacked_params)
+        with jax.named_scope("eval"):
+            iid = jax.vmap(lambda p: self.eval_fn(p, test_iid))(
+                stacked_params)
+            ood = jax.vmap(lambda p: self.eval_fn(p, test_ood))(
+                stacked_params)
         return iid, ood
 
     def _run_scan_impl(self, stacked_params, stacked_opt, batches, coeffs,

@@ -56,6 +56,13 @@ chunk while the host concatenates per-chunk metrics — the long-run mode.
 The ``(params, opt)`` carry is donated back into each chunk (and into
 the one-shot scans) on backends that support buffer donation, so the
 scan never double-allocates the model/optimizer state.
+
+Host spans (``jax.profiler.TraceAnnotation``, recorded only while a
+profile is taken) mark the engine's host work: ``repro.engine.prepare``
+(input preparation), ``repro.engine.chunk`` (each program dispatch, with
+the first round, rounds, experiments and nodes as arguments; the first
+chunk of a run holds its trace, lowering and cache load) and
+``repro.engine.fetch`` (the host waiting for a chunk's history).
 """
 from __future__ import annotations
 
@@ -67,6 +74,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.analytics import AnalyticsSpec
 from repro.core.coeffs import CoeffProgram, ProgramCoeffs
@@ -134,10 +142,12 @@ def gather_round_batch(bank: Dict[str, jnp.ndarray], data_idx: jnp.ndarray,
         out = leaf[data_idx, rows, idx_r]  # (n, S, ...)
         return out.reshape((n, steps, batch_size) + leaf.shape[3:])
 
-    batch = {k: g(v) for k, v in bank.items()}
-    if "tokens" in batch:  # LM: trainer consumes an all-ones train mask
-        seq = batch["tokens"].shape[-1]
-        batch["mask"] = jnp.ones((n, steps, batch_size, seq - 1), jnp.float32)
+    with jax.named_scope("batch_gather"):
+        batch = {k: g(v) for k, v in bank.items()}
+        if "tokens" in batch:  # LM: trainer consumes an all-ones train mask
+            seq = batch["tokens"].shape[-1]
+            batch["mask"] = jnp.ones((n, steps, batch_size, seq - 1),
+                                     jnp.float32)
     return batch
 
 
@@ -513,7 +523,8 @@ class SweepEngine:
                         analytics=None, participation=None, fault=None):
         def one(p, o, c, ix, d, ti, to, st, ac, pc, fc):
             if program is not None:
-                c = program.matrix(st, c)  # c is this round's index
+                with jax.named_scope("coeffs"):
+                    c = program.matrix(st, c)  # c is this round's index
             batch = gather_round_batch(bank, d, ix, batch_size)
             if fault is not None:
                 if participation is not None:
@@ -529,12 +540,14 @@ class SweepEngine:
                 p, o, pc, losses = self._participation_round_fn(
                     participation)(p, o, pc, batch, c, round_r)
             if do_eval:
-                iid, ood = self._eval(p, ti, to)
+                with jax.named_scope("eval"):
+                    iid, ood = self._eval(p, ti, to)
             else:
                 n = jax.tree.leaves(p)[0].shape[0]
                 iid = ood = jnp.zeros((n,))
             if analytics is not None and do_eval:
-                ac = analytics.update(ac, round_r, True, iid, ood)
+                with jax.named_scope("analytics"):
+                    ac = analytics.update(ac, round_r, True, iid, ood)
             return p, o, losses, iid, ood, ac, pc, fc
 
         return jax.vmap(one)(
@@ -655,6 +668,7 @@ class SweepEngine:
         sweep mid-run and proves it).  With no checkpoint on disk,
         ``resume=True`` degrades to a fresh start."""
         n_exp, rounds = coeffs.shape[:2]
+        n_nodes = idx.shape[2]
         test_iid = jax.tree.map(jnp.asarray, test_iid)
         test_ood = jax.tree.map(jnp.asarray, test_ood)
         rounds_idx = jnp.arange(rounds, dtype=jnp.int32)
@@ -726,10 +740,13 @@ class SweepEngine:
             "REPRO_SWEEP_CRASH_AFTER_CHUNKS", "0"))
         for a in range(start, rounds, chunk):
             b = min(a + chunk, rounds)
-            out = fn(
-                params, opt, coeffs[:, a:b], idx[:, a:b], data_idx,
-                jnp.asarray(eval_mask[a:b]), rounds_idx[a:b], bank,
-                test_iid, test_ood, states, acarry, pcarry, fcarry)
+            with TraceAnnotation("repro.engine.chunk", first_round=a,
+                                 rounds=b - a, experiments=n_exp,
+                                 nodes=n_nodes):
+                out = fn(
+                    params, opt, coeffs[:, a:b], idx[:, a:b], data_idx,
+                    jnp.asarray(eval_mask[a:b]), rounds_idx[a:b], bank,
+                    test_iid, test_ood, states, acarry, pcarry, fcarry)
             params, opt, pc_out, fc_out, ac_out, hist = _split_engine_out(
                 out, participation, analytics, fault)
             if participation is not None:
@@ -740,9 +757,10 @@ class SweepEngine:
                 acarry = ac_out
             if keep_history:
                 l_c, iid_c, ood_c = hist
-                losses.append(np.asarray(l_c))
-                iids.append(np.asarray(iid_c))
-                oods.append(np.asarray(ood_c))
+                with TraceAnnotation("repro.engine.fetch"):
+                    losses.append(np.asarray(l_c))
+                    iids.append(np.asarray(iid_c))
+                    oods.append(np.asarray(ood_c))
             chunks_done += 1
             if checkpoint_dir is not None and b < rounds:
                 _save_sweep_checkpoint(
@@ -1039,12 +1057,13 @@ class SweepEngine:
         scan state at every chunk boundary — atomic writes, outside the
         jitted scan; ``resume=True`` restarts from the latest checkpoint
         bit-identically (fresh start when none exists)."""
-        (params0, opt0, coeffs, idx, data_idx, eval_mask, bank, states,
-         program, acarry, pcarry, fcarry, rounds, n_exp, n_nodes) = \
-            self._prepare_inputs(
-                params0, coeffs, bank, indices, data_idx, analytics,
-                keep_history, participation, participation_rates,
-                participation_seeds, fault, fault_rates, fault_seeds)
+        with TraceAnnotation("repro.engine.prepare"):
+            (params0, opt0, coeffs, idx, data_idx, eval_mask, bank, states,
+             program, acarry, pcarry, fcarry, rounds, n_exp, n_nodes) = \
+                self._prepare_inputs(
+                    params0, coeffs, bank, indices, data_idx, analytics,
+                    keep_history, participation, participation_rates,
+                    participation_seeds, fault, fault_rates, fault_seeds)
         donate = donation_supported() if donate is None else donate
 
         if checkpoint_dir is not None and not chunk_rounds:
@@ -1073,12 +1092,16 @@ class SweepEngine:
                 resume, donate_params0)
 
         rounds_idx = jnp.arange(rounds, dtype=jnp.int32)
-        out = self._run_jit(
-            params0, opt0, coeffs, idx, data_idx, jnp.asarray(eval_mask),
-            rounds_idx, bank, test_iid, test_ood, states, acarry, pcarry,
-            fcarry, batch_size=batch_size, program=program,
-            analytics=analytics, keep_history=keep_history,
-            participation=participation, fault=fault)
+        with TraceAnnotation("repro.engine.chunk", first_round=0,
+                             rounds=rounds, experiments=n_exp,
+                             nodes=n_nodes):
+            out = self._run_jit(
+                params0, opt0, coeffs, idx, data_idx,
+                jnp.asarray(eval_mask), rounds_idx, bank, test_iid,
+                test_ood, states, acarry, pcarry, fcarry,
+                batch_size=batch_size, program=program,
+                analytics=analytics, keep_history=keep_history,
+                participation=participation, fault=fault)
         params, _, pc_out, fc_out, ac_out, hist = _split_engine_out(
             out, participation, analytics, fault)
         if participation is not None:
@@ -1088,12 +1111,12 @@ class SweepEngine:
         if analytics is not None:
             acarry = ac_out
         if hist is not None:
-            losses, iid, ood = hist
+            with TraceAnnotation("repro.engine.fetch"):
+                losses, iid, ood = (np.asarray(h) for h in hist)
         else:
             losses = iid = ood = np.zeros((n_exp, 0, n_nodes), np.float32)
         return SweepResult(
-            train_loss=np.asarray(losses), iid_acc=np.asarray(iid),
-            ood_acc=np.asarray(ood), params=params,
+            train_loss=losses, iid_acc=iid, ood_acc=ood, params=params,
             eval_every=self.config.eval_every,
             analytics=_finalize_analytics(analytics, acarry, n_exp),
             participation=_finalize_participation(
