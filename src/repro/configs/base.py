@@ -191,6 +191,7 @@ class ParallelConfig:
     tp_degree: int = 16             # tensor-parallel width (model axis)
     microbatch: int = 1             # grad-accumulation chunks per train step
     remat: bool = True              # checkpoint each layer in train fwd
+                                    # (stacks of 2+ layers: ForwardOptions)
     opt_dtype: str = "float32"      # adam moment dtype ("bfloat16" to halve)
     scan_layers: bool = True
     chunked_ce: int = 0             # >0: sequence-chunked cross-entropy width
