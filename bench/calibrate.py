@@ -6,9 +6,11 @@
         [--faults frozen,half_batch,no_mix,altered_answer]
 
 For each seed, in one process: one call of the cell exactly as the window
-makes it, the reference, and the compared numbers of the program against
-the reference (the lower reading). ``--control`` also runs the reference
-in bfloat16 in the program's place (the upper reading); ``--highest``
+makes it, the reference of each experiment that the check draws for that
+seed, and the compared numbers of the program against the reference, the
+worst over those experiments as the check takes them (the lower reading;
+``program_each`` holds each experiment's). ``--control`` also runs the
+reference in bfloat16 in the program's place (the upper reading); ``--highest``
 replays the reference at the highest matmul precision and reads the
 program and the stated-precision reference against it (information: how
 far the configuration's own precision lies from float32); ``--faults``
@@ -64,44 +66,75 @@ def main() -> int:
         x0 = grid.experiments[0]
         steps = t["steps_per_epoch"] or replay.build(
             cell.config, t, x0["strategy"], x0["seed"]).steps
-        e = sync_mean.sample(grid, seed, 1)[0]
-        x = grid.experiments[e]
-        built = replay.build(cell.config, t, x["strategy"], x["seed"], steps)
         rounds = sync_mean.eval_rounds(grid.rounds, t["eval_every"])
+        exps = sync_mean.sample(grid, seed, t["check_experiments"])
+        rec["experiments"] = exps
+        built, ref = {}, {}
         t0 = time.perf_counter()
-        ref = replay.replay(cell.config, t, built, x["seed"], grid.rounds,
-                            rounds, jnp.float32)
+        for e in exps:
+            x = grid.experiments[e]
+            built[e] = replay.build(cell.config, t, x["strategy"], x["seed"],
+                                    steps)
+            ref[e] = replay.replay(cell.config, t, built[e], x["seed"],
+                                   grid.rounds, rounds, jnp.float32)
         rec["reference_s"] = time.perf_counter() - t0
-        rec["program"] = sync_mean.gaps(sync_mean.program_outputs(rows[e]), ref)
+
+        def compared(outputs, rows=None) -> dict:
+            """The check's numbers: the worst over the sampled experiments,
+            and where the grid is sharded the experiments on a wrong
+            device."""
+            worst = {}
+            for e in exps:
+                for k, v in sync_mean.gaps(outputs(e), ref[e]).items():
+                    worst[k] = max(worst.get(k, 0.0), v)
+            if rows is not None and t.get("mesh_devices"):
+                worst["wrong_device"] = float(
+                    sync_mean.wrong_devices(rows, t["mesh_devices"]))
+            return worst
+
+        def of_rows(rows):
+            return lambda e: sync_mean.program_outputs(rows[e])
+
+        rec["program"] = compared(of_rows(rows), rows)
+        rec["program_each"] = [sync_mean.gaps(of_rows(rows)(e), ref[e])
+                               for e in exps]
+        e0 = exps[0]
         rec["loss_mean"] = {r: float(v["train_loss"].mean())
-                            for r, v in ref.items()}
+                            for r, v in ref[e0].items()}
         rec["iid_acc_mean"] = {r: float(v["iid_acc"].mean())
-                               for r, v in ref.items()}
+                               for r, v in ref[e0].items()}
         rec["ood_acc_mean"] = {r: float(v["ood_acc"].mean())
-                               for r, v in ref.items()}
+                               for r, v in ref[e0].items()}
         if args.control:
             t0 = time.perf_counter()
-            ctl = replay.replay(cell.config, t, built, x["seed"], grid.rounds,
-                                rounds, jnp.bfloat16)
+            ctl = {e: replay.replay(cell.config, t, built[e],
+                                    grid.experiments[e]["seed"], grid.rounds,
+                                    rounds, jnp.bfloat16) for e in exps}
             rec["control_s"] = time.perf_counter() - t0
-            rec["control"] = sync_mean.gaps(ctl, ref)
+            rec["control"] = compared(ctl.get)
         if args.highest:
             t0 = time.perf_counter()
-            hi = replay.replay(dict(cell.config, matmul_precision="highest"),
-                               t, built, x["seed"], grid.rounds, rounds,
-                               jnp.float32)
+            hi = {e: replay.replay(dict(cell.config, matmul_precision="highest"),
+                                   t, built[e], grid.experiments[e]["seed"],
+                                   grid.rounds, rounds, jnp.float32)
+                  for e in exps}
             rec["highest_s"] = time.perf_counter() - t0
-            rec["program_vs_highest"] = sync_mean.gaps(
-                sync_mean.program_outputs(rows[e]), hi)
-            rec["reference_vs_highest"] = sync_mean.gaps(ref, hi)
+            rec["program_vs_highest"] = {}
+            rec["reference_vs_highest"] = {}
+            for e in exps:
+                for key, got in (("program_vs_highest", of_rows(rows)(e)),
+                                 ("reference_vs_highest", ref[e])):
+                    for k, v in sync_mean.gaps(got, hi[e]).items():
+                        rec[key][k] = max(rec[key].get(k, 0.0), v)
         for name in filter(None, args.faults.split(",")):
+            t0 = time.perf_counter()
             with bfaults.FAULTS[name]():
                 try:
                     frows = grid.call()
-                    rec[f"fault.{name}"] = sync_mean.gaps(
-                        sync_mean.program_outputs(frows[e]), ref)
+                    rec[f"fault.{name}"] = compared(of_rows(frows), frows)
                 except Exception as exc:  # a fault that crashes is caught
                     rec[f"fault.{name}"] = {"raised": repr(exc)[:300]}
+            rec[f"fault.{name}_s"] = time.perf_counter() - t0
             jax.clear_caches()
         line = json.dumps(rec)
         print(line, flush=True)

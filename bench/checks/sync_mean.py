@@ -69,15 +69,22 @@ def sample(grid, seed: int, count: int) -> List[int]:
 
 
 def wrong_devices(rows: List[dict], n_shards: int) -> int:
-    """Experiments whose parameters were not held by their own shard's
-    device alone (experiment ``e`` of ``E`` lies on shard
-    ``e // ceil(E / n_shards)``)."""
-    import jax
-
+    """Experiments not held by their own shard's device alone. Experiment
+    ``e`` of ``E`` lies on shard ``e // ceil(E / n_shards)``; each shard's
+    experiments have to be held by one and the same device, and no two
+    shards by the same one. Which device a shard gets is the mesh's
+    choice (on a 2x2 TPU tray ``jax.make_mesh`` orders the chips as a ring,
+    0, 1, 3, 2), so the check does not assume an order."""
     per = -(-len(rows) // n_shards)
-    ids = [d.id for d in jax.devices()[:n_shards]]
-    return sum(row["param_devices"] != [ids[e // per]]
-               for e, row in enumerate(rows))
+    owner: Dict[int, int] = {}   # device id -> the first shard it holds
+    wrong = 0
+    for k in range(n_shards):
+        block = rows[k * per:(k + 1) * per]
+        held = {tuple(row["param_devices"]) for row in block}
+        devs = held.pop() if len(held) == 1 else ()
+        if len(devs) != 1 or owner.setdefault(devs[0], k) != k:
+            wrong += len(block)
+    return wrong
 
 
 def check(cell, grid, rows: List[dict], seed: int) -> dict:

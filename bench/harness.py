@@ -116,11 +116,16 @@ class Grid:
 
 def build_grid(cell: bspec.Cell, seed: int) -> Grid:
     """The cell's grid from its traffic and configuration, seeded by
-    ``seed``: experiment ``k`` of each strategy takes seed ``seed + k``."""
-    from benchmarks.common import BenchScale, SweepCell
+    ``seed``: experiment ``k`` of each strategy takes seed ``seed + k``.
+    The scale is the program's paper-scale ``FULL`` with the traffic's
+    sizes and what the model's file (``bench/models/<model>.py``) sets
+    through ``scale(cfg)``; its ``sweep_kwargs(cfg)``, where it has one,
+    adds keyword arguments of the call."""
+    from benchmarks.common import FULL, SweepCell
     from repro.core.topology import barabasi_albert
 
     t, cfg = cell.traffic, cell.config
+    model = bspec.model(cfg)
     g = t["graph"]
     if g["kind"] != "barabasi_albert":
         raise KeyError(f"graph kind {g['kind']!r}")
@@ -134,13 +139,16 @@ def build_grid(cell: bspec.Cell, seed: int) -> Grid:
                                    name=f"{cell.name}/{strat}/{s}"))
             experiments.append({"strategy": strat, "seed": s})
     rounds = t["rounds_per_call"]
-    scale = BenchScale(n_train=t["n_train"], n_test=t["n_test"], rounds=rounds,
-                       local_epochs=t["local_epochs"], batch=t["batch"],
-                       steps_per_epoch=t["steps_per_epoch"],
-                       eval_every=t["eval_every"], eval_n=t["eval_n"],
-                       vgg_width=cfg.get("width_mult", 1.0))
+    scale = dataclasses.replace(
+        FULL, n_train=t["n_train"], n_test=t["n_test"], rounds=rounds,
+        local_epochs=t["local_epochs"], batch=t["batch"],
+        steps_per_epoch=t["steps_per_epoch"], eval_every=t["eval_every"],
+        eval_n=t["eval_n"],
+        **(model.scale(cfg) if hasattr(model, "scale") else {}))
     kwargs = dict(t.get("options", {}), alpha_l=t["alpha_l"],
                   alpha_s=t["alpha_s"], chunk_rounds=t["chunk_rounds"])
+    if hasattr(model, "sweep_kwargs"):
+        kwargs.update(model.sweep_kwargs(cfg))
     if t.get("mesh_devices"):
         from repro.launch.mesh import make_sweep_mesh
 

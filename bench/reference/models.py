@@ -1,20 +1,23 @@
 """The reference's models, losses and optimizers, in plain ``jax.numpy``.
 
 Written from each configuration file's description and importing nothing
-of the program under test. Initial weights follow the configuration's
-stated init rule from the cell's seed, so the reference starts where the
-program starts without taking its weights.
+of the program under test. Each model's ``init`` and ``apply`` are in its
+own file, ``bench/models/<model>.py``; the loss and accuracy follow the
+data's kind. Initial weights follow the configuration's stated init rule
+from the cell's seed, so the reference starts where the program starts
+without taking its weights.
 
 ``dtype`` is the compute dtype: float32 for the reference, bfloat16 for
 its control (weights, activations and optimizer state all held in it).
 """
 from __future__ import annotations
 
-import math
 from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from bench import spec
 
 
 def trunc_normal(key, shape, std, dtype):
@@ -23,160 +26,12 @@ def trunc_normal(key, shape, std, dtype):
             * std).astype(dtype)
 
 
-# ----------------------------------------------------------------------
-# FFN
-# ----------------------------------------------------------------------
-def ffn_init(cfg: dict, key, dtype):
-    widths = [cfg["in_dim"]] + [cfg["hidden_size"]] * (cfg["n_layers"] - 1) \
-        + [cfg["n_classes"]]
-    ks = jax.random.split(key, cfg["n_layers"])
-    return [{"w": trunc_normal(k, (a, b), 1.0 / math.sqrt(a), dtype),
-             "b": jnp.zeros((b,), dtype)}
-            for k, a, b in zip(ks, widths[:-1], widths[1:])]
-
-
-def ffn_apply(cfg: dict, params, x):
-    h = x.reshape(x.shape[0], -1)
-    for i, layer in enumerate(params):
-        h = h @ layer["w"] + layer["b"][None]
-        if i < len(params) - 1:
-            h = jax.nn.relu(h)
-    return h
-
-
-# ----------------------------------------------------------------------
-# VGG-16 (configuration D convolutions, then the configuration's head)
-# ----------------------------------------------------------------------
-def vgg_init(cfg: dict, key, dtype):
-    convs = []
-    ch = cfg["in_channels"]
-    for spec in cfg["plan"]:
-        if spec == "M":
-            continue
-        out = max(8, int(spec * cfg["width_mult"]))
-        key, sub = jax.random.split(key)
-        std = math.sqrt(2.0 / (9 * ch))
-        convs.append({"w": (jax.random.normal(sub, (3, 3, ch, out), jnp.float32)
-                            * std).astype(dtype),
-                      "b": jnp.zeros((out,), dtype)})
-        ch = out
-    k1, k2 = jax.random.split(key)
-    fc = cfg["fc_width"]
-    return {"convs": convs,
-            "fc1": {"w": trunc_normal(k1, (ch, fc), 1.0 / math.sqrt(ch), dtype),
-                    "b": jnp.zeros((fc,), dtype)},
-            "fc2": {"w": trunc_normal(k2, (fc, cfg["n_classes"]),
-                                      1.0 / math.sqrt(fc), dtype),
-                    "b": jnp.zeros((cfg["n_classes"],), dtype)}}
-
-
-def vgg_apply(cfg: dict, params, x):
-    convs = iter(params["convs"])
-    for spec in cfg["plan"]:
-        if spec == "M":
-            x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-            continue
-        layer = next(convs)
-        x = jax.lax.conv_general_dilated(
-            x, layer["w"], (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = jax.nn.relu(x + layer["b"][None, None, None])
-    x = jnp.mean(x, axis=(1, 2))
-    x = jax.nn.relu(x @ params["fc1"]["w"] + params["fc1"]["b"][None])
-    return x @ params["fc2"]["w"] + params["fc2"]["b"][None]
-
-
-# ----------------------------------------------------------------------
-# GPT-2-small widths, pre-LayerNorm blocks, rotary positions
-# ----------------------------------------------------------------------
-def gpt2_init(cfg: dict, key, dtype):
-    d, h, ff, v = (cfg["n_embd"], cfg["n_head"], cfg["n_inner"],
-                   cfg["vocab_size"])
-    hd = d // h
-    ks = jax.random.split(key, 8)
-    ln = lambda: {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
-    layers = []
-    for i in range(cfg["n_layer"]):
-        lk = jax.random.split(jax.random.fold_in(ks[3], i), 6)
-        ak = jax.random.split(lk[0], 4)
-        mk = jax.random.split(lk[3], 3)
-        layers.append({
-            "ln1": ln(), "ln2": ln(),
-            # std 1/sqrt(first axis) for every projection, as the
-            # configuration's init rule states (h for the output one)
-            "wq": trunc_normal(ak[0], (d, h, hd), 1.0 / math.sqrt(d), dtype),
-            "wk": trunc_normal(ak[1], (d, h, hd), 1.0 / math.sqrt(d), dtype),
-            "wv": trunc_normal(ak[2], (d, h, hd), 1.0 / math.sqrt(d), dtype),
-            "wo": trunc_normal(ak[3], (h, hd, d), 1.0 / math.sqrt(h), dtype),
-            "wi": trunc_normal(mk[0], (d, ff), 1.0 / math.sqrt(d), dtype),
-            "wf": trunc_normal(mk[1], (ff, d), 1.0 / math.sqrt(ff), dtype),
-        })
-    return {"embed": trunc_normal(ks[0], (v, d), 0.02, dtype),
-            "head": trunc_normal(ks[1], (d, v), 1.0 / math.sqrt(d), dtype),
-            "ln_f": ln(), "layers": layers}
-
-
-def _layernorm(p, x, eps, acc):
-    xf = x.astype(acc)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (y * p["scale"].astype(acc)[None, None]
-            + p["bias"].astype(acc)[None, None]).astype(x.dtype)
-
-
-def _rotary(x, theta, acc):
-    """Rotate the two halves of each head's features by position."""
-    s, hd = x.shape[1], x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(ang).astype(acc)[None, :, None, :]
-    sin = jnp.sin(ang).astype(acc)[None, :, None, :]
-    x1, x2 = jnp.split(x.astype(acc), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def gpt2_apply(cfg: dict, params, tokens):
-    """Logits of every position. Normalisation, attention scores and the
-    logits are computed in the weights' dtype: float32 for the reference
-    (as the program computes them), bfloat16 for the control."""
-    d, eps = cfg["n_embd"], cfg["layer_norm_epsilon"]
-    acc = params["embed"].dtype
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = x * jnp.sqrt(jnp.asarray(d, jnp.float32)).astype(x.dtype)
-    s = tokens.shape[1]
-    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-    for lp in params["layers"]:
-        h = _layernorm(lp["ln1"], x, eps, acc)
-        q = _rotary(jnp.einsum("bsd,dhk->bshk", h, lp["wq"]), cfg["rope_theta"],
-                    acc)
-        k = _rotary(jnp.einsum("bsd,dhk->bshk", h, lp["wk"]), cfg["rope_theta"],
-                    acc)
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        hd = q.shape[-1]
-        logits = jnp.einsum("bshk,bthk->bhst", q.astype(acc),
-                            k.astype(acc)) / math.sqrt(hd)
-        logits = jnp.where(causal[None, None], logits, jnp.asarray(-1e30, acc))
-        probs = jax.nn.softmax(logits, axis=-1)
-        att = jnp.einsum("bhst,bthk->bshk", probs, v.astype(acc))
-        x = x + jnp.einsum("bshk,hkd->bsd", att.astype(x.dtype), lp["wo"])
-        h = _layernorm(lp["ln2"], x, eps, acc)
-        x = x + jax.nn.gelu(h @ lp["wi"], approximate=True) @ lp["wf"]
-    x = _layernorm(params["ln_f"], x, eps, acc)
-    return (x @ params["head"]).astype(acc)
-
-
-MODELS = {"ffn": (ffn_init, ffn_apply), "vgg16": (vgg_init, vgg_apply),
-          "gpt2": (gpt2_init, gpt2_apply)}
-
-
 def model(cfg: dict) -> Tuple[Callable, Callable]:
-    """(init(key, dtype), apply(params, inputs)) of the configuration."""
-    init, apply = MODELS[cfg["model"]]
-    return (lambda key, dtype: init(cfg, key, dtype),
-            lambda params, x: apply(cfg, params, x))
+    """(init(key, dtype), apply(params, inputs)) of the configuration,
+    from its model's file (``bench/models/<model>.py``)."""
+    mod = spec.model(cfg)
+    return (lambda key, dtype: mod.init(cfg, key, dtype),
+            lambda params, x: mod.apply(cfg, params, x))
 
 
 # ----------------------------------------------------------------------
@@ -184,11 +39,14 @@ def model(cfg: dict) -> Tuple[Callable, Callable]:
 # ----------------------------------------------------------------------
 def loss_and_accuracy(cfg: dict) -> Tuple[Callable, Callable]:
     """(loss(params, batch), accuracy(params, batch)): cross-entropy and
-    arg-max accuracy over labels (images) or over next tokens, the LM's
-    weighted by the batch's ``mask`` where it has one. The loss is
-    computed in the dtype of the logits."""
+    arg-max accuracy over labels (data of kind ``"image"``) or over next
+    tokens (``"lm"``), the LM's weighted by the batch's ``mask`` where it
+    has one. The loss is computed in the dtype of the logits."""
     _, apply = model(cfg)
-    if cfg["model"] != "gpt2":
+    kind = cfg["data"]["kind"]
+    if kind not in ("image", "lm"):
+        raise KeyError(f"data kind {kind!r}; have 'image', 'lm'")
+    if kind == "image":
         def loss(p, b):
             logp = jax.nn.log_softmax(apply(p, b["x"]))
             return -jnp.mean(jnp.take_along_axis(logp, b["y"][:, None],

@@ -9,6 +9,8 @@
   ``correct``;
 * ``bench/checks/<check>.py``: the comparison with the plain reference
   that the traffic names;
+* ``bench/models/<model>.py``: what the benchmark knows of the model that
+  a configuration's ``"model"`` names (``model``);
 * ``bench/metrics/<metric>.py``: one reader per metric, ``read(ctx)``.
 
 A later cell, configuration or metric is a new file here, not an edit.
@@ -50,6 +52,22 @@ def load_module(path: Path) -> ModuleType:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def model(cfg: dict) -> ModuleType:
+    """The file of the configuration's model, ``bench/models/<model>.py``.
+    It holds ``macs(cfg)`` (forward multiply-adds of one sample and of its
+    first layer, for ``bench.flops``), the plain reference's ``init(cfg,
+    key, dtype)`` and ``apply(cfg, params, inputs)``, and, where the
+    grid's call needs them, ``scale(cfg)`` (fields of the program's
+    ``BenchScale``) and ``sweep_kwargs(cfg)`` (further keyword arguments
+    of ``run_sweep_cells``)."""
+    path = BENCH / "models" / f"{cfg['model']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"configuration {cfg.get('name')!r} names model {cfg['model']!r}, "
+            f"which has no file {path}")
+    return load_module(path)
 
 
 class Cell:

@@ -35,9 +35,10 @@ SMALL = {
 }
 
 
-#: limits of a cell that is not in ``BENCHMARK.json``, run on the CPU:
-#: the program's float32 sums agree with the reference's to rounding; an
-#: accuracy may differ by one prediction (1/32) that rounding tipped
+#: limits of a cell run at its CPU test size: the program's float32 sums
+#: agree with the reference's to rounding; an accuracy may differ by one
+#: prediction (1/32) that rounding tipped. A cell's limits file holds the
+#: limits set on the chip, whose default matmul precision rounds more.
 CPU_LIMITS = {"loss_gap": 1e-5, "mean_loss_gap": 1e-5, "iid_acc_gap": 0.01,
               "ood_acc_gap": 0.01, "iid_acc_max_gap": 0.04, "wrong_device": 0}
 
@@ -45,7 +46,7 @@ CPU_LIMITS = {"loss_gap": 1e-5, "mean_loss_gap": 1e-5, "iid_acc_gap": 0.01,
 def cell_from_files(name: str, config: str, traffic: str, chips: int,
                     limits: dict) -> spec.Cell:
     """A cell built from a configuration file and a traffic file by name,
-    for a cell that ``BENCHMARK.json`` does not hold."""
+    with the given limits in place of a limits file."""
     load = lambda *p: json.loads(ROOT.joinpath("bench", *p).read_text())
     return spec.Cell(name, chips, load("configs", f"{config}.json"),
                      load("traffic", f"{traffic}.json"), limits,

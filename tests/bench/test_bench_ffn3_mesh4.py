@@ -2,9 +2,10 @@
 the sharded program agrees with the reference in the experiments the
 check draws, every experiment's results come back from its own shard, and
 every planted fault, the unsharded run among them, fails ``correct``.
-The runs need four devices, so they run in a child process. (The cell is
-not in ``BENCHMARK.json`` yet: no four-chip machine was free to measure
-it; see PERF.md.)"""
+The runs need four devices, so they run in a child process. The cell
+``ffn3.fig4grid.mesh4`` of ``BENCHMARK.json`` runs at this size against
+``small_cells.CPU_LIMITS``, the limits of float32 on the CPU; its own
+limits file holds those set on four chips, for the chip's rounding."""
 import json
 import os
 import subprocess
@@ -13,6 +14,7 @@ from pathlib import Path
 
 import pytest
 
+from bench.checks.sync_mean import wrong_devices
 from bench.faults import FAULTS
 
 HERE = Path(__file__).resolve().parent
@@ -38,3 +40,24 @@ def test_sharded_program_matches_reference(runs):
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_fault_fails(runs, fault):
     assert not runs[fault]["correct"], runs[fault]["compared"]
+
+
+#: 12 experiments on 4 shards: the devices each experiment's rows came
+#: back from, and how many experiments ``wrong_device`` counts
+PLACEMENTS = {
+    "in_order": ([[0]] * 3 + [[1]] * 3 + [[2]] * 3 + [[3]] * 3, 0),
+    # jax.make_mesh's ring order on a 2x2 TPU tray
+    "ring": ([[0]] * 3 + [[1]] * 3 + [[3]] * 3 + [[2]] * 3, 0),
+    "unsharded": ([[0]] * 12, 9),
+    "shard_split": ([[0]] * 3 + [[1], [1], [2]] + [[3]] * 3 + [[2]] * 3, 3),
+    "replicated": ([[0, 1, 2, 3]] * 12, 12),
+    "two_shards_one_device": ([[0]] * 3 + [[1]] * 3 + [[1]] * 3
+                              + [[2]] * 3, 3),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLACEMENTS))
+def test_wrong_devices_counts_misplaced_shards(name):
+    devices, want = PLACEMENTS[name]
+    rows = [{"param_devices": d} for d in devices]
+    assert wrong_devices(rows, 4) == want
