@@ -14,6 +14,14 @@ it, as they do for a user's sweep.
 After the window the device's memory peak is read, the program's state is
 dropped, and the cell's check compares what the last call produced with
 the plain reference.
+
+Each metric reader (``bench/metrics/<metric>.py``) gets one ``ctx``: the
+window's times, node-rounds, calls, JAX's monitoring counts inside the
+window (``counts``), the cell's configuration and traffic, the last call's
+rows, and in a traced run the trace's reduction (``trace``), the device
+seconds by scope (``scopes``: ``self`` and ``inclusive``, averaged over
+the chips, with the scopes that the model file declares) and the host
+seconds by program span (``spans``); untraced, those three are None.
 """
 from __future__ import annotations
 
@@ -27,7 +35,7 @@ import shutil
 import sys
 import time
 import traceback
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from bench import spec as bspec
 
@@ -206,12 +214,71 @@ def window(grid: Grid, seconds: float) -> dict:
             "call_s": call_s, "rows": last_rows}
 
 
+def read_trace(scope_names) -> Tuple[dict, dict]:
+    """The traced window in ``TRACE_DIR``, read once and then removed: its
+    reduction (busy and idle time, top ops, idle gaps; ``bench.tracefile``)
+    and its split by the scopes ``scope_names`` and by program span
+    (``bench.scopes``)."""
+    from jax.profiler import ProfileData
+
+    from bench import scopes, tracefile
+
+    t0 = time.perf_counter()
+    path = tracefile.find_xplane(str(TRACE_DIR))
+    data = ProfileData.from_file(path)
+    traced = tracefile.Trace.of(data)
+    window = traced.span("bench.window")
+    if window is None:
+        raise ValueError("the trace holds no bench.window span")
+    reduced = tracefile.reduce(traced, window)
+    t1 = time.perf_counter()
+    split = scopes.split(traced, scopes.device_ops(path, data), window,
+                         scope_names)
+    log(f"bench: trace read in {time.perf_counter() - t0:.3f} s (scopes and "
+        f"spans {time.perf_counter() - t1:.3f} s)")
+    shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    return reduced, split
+
+
+def trace_context(reduced: Optional[dict], split: Optional[dict]) -> dict:
+    """The part of a reader's ``ctx`` that a trace gives: None untraced."""
+    if split is None:
+        return {"trace": reduced, "scopes": None, "spans": None}
+    return {"trace": reduced,
+            "scopes": {"self": split["scope_s"],
+                       "inclusive": split["inclusive_s"]},
+            "spans": split["span_s"]}
+
+
+def window_context(cell: bspec.Cell, win: dict, clock: Clock) -> dict:
+    """The part of a reader's ``ctx`` that the window and the cell give."""
+    return {"window_s": win["window_s"],
+            "node_rounds": win["attempted"] - win["failed"],
+            "calls": len(win["call_s"]),
+            "counts": dict(clock.counts.get("window", {})),
+            "config": cell.config, "traffic": cell.traffic,
+            "rows": win["rows"]}
+
+
+def read_metrics(cell: bspec.Cell, ctx: dict, trace: bool) -> dict:
+    """{name: {"value", "unit"}} of the run's metrics (per-layer where
+    traced, else end-to-end), as their readers read ``ctx``; a reader that
+    finds nothing to read leaves its metric out."""
+    units = cell.units(trace)
+    metrics = {}
+    for name, reader in cell.readers(trace).items():
+        value = reader.read(ctx)
+        if value is not None:
+            metrics[name] = {"value": value, "unit": units[name]}
+    return metrics
+
+
 def run_cell(cell: bspec.Cell, seed: int, seconds: float, trace: bool,
              t_start: float, require_tpu: bool = True) -> Optional[dict]:
     """The result line of one run, or None where the chips are missing."""
     import jax
 
-    from bench import flops, peaks, tracefile
+    from bench import flops, peaks, scopes
 
     cache = enable_cache()
     dev = device_info()
@@ -257,17 +324,12 @@ def run_cell(cell: bspec.Cell, seed: int, seconds: float, trace: bool,
             f"inside the window")
 
     mem = memory_peak_bytes(cell.chips)
-    rows = win.pop("rows")
+    rows = win["rows"]
     gc.collect()
 
-    reduced = None
+    reduced = split = None
     if trace:
-        traced = tracefile.Trace.load(tracefile.find_xplane(str(TRACE_DIR)))
-        span = traced.span("bench.window")
-        if span is None:
-            raise ValueError("the trace holds no bench.window span")
-        reduced = tracefile.reduce(traced, span)
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        reduced, split = read_trace(scopes.declared(cell.config))
 
     if rows is None:
         numbers, steps = {}, None
@@ -278,19 +340,13 @@ def run_cell(cell: bspec.Cell, seed: int, seconds: float, trace: bool,
         log(f"bench: not compared {verdict['info']}")
         correct = win["failed"] == 0 and all(
             v["value"] <= v["limit"] for v in numbers.values())
-    ctx = {"setup_s": setup_s, "window_s": win["window_s"],
-           "node_rounds": win["attempted"] - win["failed"],
-           "chips": cell.chips, "peak": peak, "trace": reduced,
+    ctx = {"setup_s": setup_s, "chips": cell.chips, "peak": peak,
            "flops_per_node_round": (
                None if steps is None else
                t["local_epochs"] * steps * t["batch"]
-               * flops.train_flops_per_sample(cell.config))}
-    units = cell.units(trace)
-    metrics = {}
-    for name, reader in cell.readers(trace).items():
-        value = reader.read(ctx)
-        if value is not None:
-            metrics[name] = {"value": value, "unit": units[name]}
+               * flops.train_flops_per_sample(cell.config)),
+           **window_context(cell, win, clock), **trace_context(reduced, split)}
+    metrics = read_metrics(cell, ctx, trace)
     device = dict(dev, memory_peak_bytes=mem)
     out = {"correct": bool(correct), "attempted": win["attempted"],
            "failed": win["failed"], "metrics": metrics, "device": device}

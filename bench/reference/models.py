@@ -9,6 +9,8 @@ without taking its weights.
 
 ``dtype`` is the compute dtype: float32 for the reference, bfloat16 for
 its control (weights, activations and optimizer state all held in it).
+A configuration whose ``optimizer`` states a ``"state_dtype"`` has Adam's
+moments held in that dtype instead, whatever the compute dtype.
 """
 from __future__ import annotations
 
@@ -80,29 +82,43 @@ def loss_and_accuracy(cfg: dict) -> Tuple[Callable, Callable]:
 # optimizers: (init(params), update(grads, state) -> (new delta, state))
 # ----------------------------------------------------------------------
 def optimizer(spec: dict, dtype):
+    """SGD, or Adam with its moments in the compute dtype ``dtype``. Where
+    ``spec`` states a ``"state_dtype"``, Adam holds its moments in that
+    dtype and computes in float32: each step the stored moments advance in
+    float32, the update is taken from those float32 moments and then cast
+    to ``dtype``, and the moments are rounded to the stated dtype to be
+    kept (as optax's ``mu_dtype`` keeps its first moment)."""
     lr = spec["lr"]
     if spec["name"] == "sgd":
         return (lambda p: jnp.zeros((), jnp.int32),
                 lambda g, s: (jax.tree.map(lambda x: -lr * x, g), s + 1))
     b1, b2, eps = spec["b1"], spec["b2"], spec["eps"]
+    stated = spec.get("state_dtype")
+    # the moments' dtype, and the dtype the step computes in
+    keep = jnp.dtype(stated) if stated else dtype
+    work = jnp.float32 if stated else dtype
 
     def init(p):
-        z = jax.tree.map(lambda x: jnp.zeros(x.shape, dtype), p)
+        z = jax.tree.map(lambda x: jnp.zeros(x.shape, keep), p)
         return (jnp.zeros((), jnp.int32), z, z)
 
     def update(g, state):
         step, mu, nu = state
         step = step + 1
-        mu = jax.tree.map(lambda m, x: (b1 * m + (1 - b1) * x).astype(dtype),
-                          mu, g)
-        nu = jax.tree.map(lambda v, x: (b2 * v + (1 - b2) * x * x).astype(dtype),
-                          nu, g)
+        mu = jax.tree.map(
+            lambda m, x: (b1 * m.astype(work) + (1 - b1) * x.astype(work))
+            .astype(work), mu, g)
+        nu = jax.tree.map(
+            lambda v, x: (b2 * v.astype(work)
+                          + (1 - b2) * x.astype(work) * x.astype(work))
+            .astype(work), nu, g)
         t = step.astype(jnp.float32)
         c1, c2 = 1.0 / (1.0 - b1 ** t), 1.0 / (1.0 - b2 ** t)
         upd = jax.tree.map(
             lambda m, v: (-lr * (m * c1) / (jnp.sqrt(v * c2) + eps))
             .astype(dtype), mu, nu)
-        return upd, (step, mu, nu)
+        cast = lambda t: jax.tree.map(lambda x: x.astype(keep), t)
+        return upd, (step, cast(mu), cast(nu))
     return init, update
 
 

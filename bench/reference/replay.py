@@ -10,12 +10,19 @@ mixed parameters are scored on the IID and the OOD test batch.
 The coefficients follow the strategy's rule (``coefficients``). The mix
 runs in float32 at the highest matmul precision; local training runs at
 the configuration's stated matmul precision, in the given dtype.
+
+Nodes train in blocks that fit the device (``block_size``): where every
+node's parameters, optimizer state and gradients fit in half the device's
+memory, all nodes train at once in one program with the mix (the case of
+every cell so far, and of the CPU, whose memory JAX does not report).
+Otherwise each block's nodes move to the device, train, and move back to
+host memory, and the mix then runs leaf by leaf on the device.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -119,11 +126,13 @@ def _bank(parts: List[rdata.Split]):
 
 
 def programs(cfg: dict, traffic: dict, dtype):
-    """(round_fn, evaluate) of the reference, jitted:
+    """(round_fn, train_fn, mix_leaf, evaluate) of the reference, jitted:
     ``round_fn(params, opt, idx, bank_x, bank_y, coeffs) -> (params, opt,
     losses)`` trains every node for one round, vmapped over the nodes,
-    and mixes (params and opt donated); ``evaluate(params, test_iid,
-    test_ood) -> (iid, ood)``."""
+    and mixes (params and opt donated); ``train_fn(params, opt, idx,
+    bank_x, bank_y)`` is its training alone, for a block of nodes;
+    ``mix_leaf(coeffs, leaf)`` is its mix of one leaf stacked over all
+    nodes; ``evaluate(params, test_iid, test_ood) -> (iid, ood)``."""
     loss_fn, acc_fn = rmodels.loss_and_accuracy(cfg)
     _, opt_update = rmodels.optimizer(cfg["optimizer"], dtype)
     batch = traffic["batch"]
@@ -147,17 +156,19 @@ def programs(cfg: dict, traffic: dict, dtype):
         (p, o), losses = jax.lax.scan(step, (p, o), node_batches(x, y, idx))
         return p, o, jnp.mean(losses.astype(jnp.float32))
 
-    def mix(p, c):
-        return jax.tree.map(
-            lambda leaf: jnp.tensordot(
-                c, leaf.astype(jnp.float32), axes=(1, 0),
-                precision=jax.lax.Precision.HIGHEST).astype(leaf.dtype), p)
+    def mix_leaf(c, leaf):
+        return jnp.tensordot(
+            c, leaf.astype(jnp.float32), axes=(1, 0),
+            precision=jax.lax.Precision.HIGHEST).astype(leaf.dtype)
+
+    def train(p, o, idx, bx, by):
+        with jax.default_matmul_precision(precision):
+            return jax.vmap(local)(p, o, bx, by, idx)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def round_fn(p, o, idx, bx, by, c):
-        with jax.default_matmul_precision(precision):
-            p, o, losses = jax.vmap(local)(p, o, bx, by, idx)
-        return mix(p, c), o, losses
+        p, o, losses = train(p, o, idx, bx, by)
+        return jax.tree.map(lambda leaf: mix_leaf(c, leaf), p), o, losses
 
     @jax.jit
     def evaluate(p, ti, to):
@@ -165,38 +176,132 @@ def programs(cfg: dict, traffic: dict, dtype):
             one = lambda q: (acc_fn(q, ti), acc_fn(q, to))
             return jax.lax.map(one, p)
 
-    return round_fn, evaluate
+    return (round_fn, jax.jit(train, donate_argnums=(0, 1)),
+            jax.jit(mix_leaf), evaluate)
+
+
+def _seed_init(cfg: dict, seed: int, dtype):
+    """One node's parameters at the seed's init, and the optimizer's
+    ``init``."""
+    init, _ = rmodels.model(cfg)
+    opt_init, _ = rmodels.optimizer(cfg["optimizer"], dtype)
+    return init(jax.random.key(seed), dtype), opt_init
 
 
 def initial_state(cfg: dict, n: int, seed: int, dtype):
     """(params, optimizer state) of ``n`` nodes, each the seed's init."""
-    init, _ = rmodels.model(cfg)
-    opt_init, _ = rmodels.optimizer(cfg["optimizer"], dtype)
-    p0 = init(jax.random.key(seed), dtype)
+    p0, opt_init = _seed_init(cfg, seed, dtype)
     params = jax.jit(lambda p: jax.tree.map(
         lambda x: jnp.broadcast_to(x, (n,) + x.shape), p))(p0)
     return params, jax.jit(jax.vmap(opt_init))(params)
 
 
+#: the share of the device's memory that a block's parameters, optimizer
+#: state and gradients may take; the rest holds the block's activations,
+#: the sample bank and the compiler's temporaries
+STATE_SHARE = 0.5
+
+
+def node_bytes(cfg: dict, dtype) -> int:
+    """Bytes one node holds in training besides its activations: its
+    parameters, its optimizer state and its gradients."""
+    init, _ = rmodels.model(cfg)
+    opt_init, _ = rmodels.optimizer(cfg["optimizer"], dtype)
+    p = jax.eval_shape(lambda k: init(k, dtype), jax.random.key(0))
+    nbytes = lambda t: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))
+    return 2 * nbytes(p) + nbytes(jax.eval_shape(opt_init, p))
+
+
+def block_size(n: int, per_node: int, budget: Optional[int] = None) -> int:
+    """Nodes that train at once: as many as fit ``budget`` bytes at
+    ``per_node`` each, at least one. Without a budget it is
+    ``STATE_SHARE`` of the device's memory limit, or all ``n`` where the
+    device reports none (the CPU)."""
+    if budget is None:
+        limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+        if limit is None:
+            return n
+        budget = int(limit * STATE_SHARE)
+    return max(1, min(n, budget // per_node))
+
+
+class _AllNodes:
+    """Every node's state on the device; a round is one program."""
+
+    def __init__(self, progs, cfg, n, seed, dtype, bank):
+        self.round_fn, _, _, self.evaluate_fn = progs
+        self.params, self.opt = initial_state(cfg, n, seed, dtype)
+        self.bank = bank
+
+    def round(self, idx, coeffs):
+        self.params, self.opt, losses = self.round_fn(
+            self.params, self.opt, idx, *self.bank, coeffs)
+        return losses
+
+    def evaluate(self, tiid, tood):
+        return self.evaluate_fn(self.params, tiid, tood)
+
+
+class _Blocks:
+    """Each node's state in host memory, stacked by block; a block moves
+    to the device to train and back, and the mix runs leaf by leaf."""
+
+    def __init__(self, progs, cfg, n, seed, dtype, bank, size):
+        _, self.train_fn, self.mix_fn, self.evaluate_fn = progs
+        p0, opt_init = _seed_init(cfg, seed, dtype)
+        one = jax.device_get((p0, opt_init(p0)))
+        self.blocks = [(lo, min(lo + size, n)) for lo in range(0, n, size)]
+        self.state = [jax.tree.map(
+            lambda x, b=hi - lo: np.broadcast_to(x, (b,) + np.shape(x)), one)
+            for lo, hi in self.blocks]
+        self.bank = bank
+
+    def round(self, idx, coeffs):
+        losses = []
+        for k, (lo, hi) in enumerate(self.blocks):
+            p, o, loss = self.train_fn(
+                *jax.device_put(self.state[k]), idx[lo:hi],
+                *(b[lo:hi] for b in self.bank))
+            self.state[k] = jax.device_get((p, o))
+            losses.append(loss)
+        leaves, trees = zip(*(jax.tree.flatten(p) for p, _ in self.state))
+        mixed = [np.asarray(self.mix_fn(coeffs, np.concatenate(parts)))
+                 for parts in zip(*leaves)]
+        for k, (lo, hi) in enumerate(self.blocks):
+            self.state[k] = (jax.tree.unflatten(
+                trees[k], [m[lo:hi] for m in mixed]), self.state[k][1])
+        return jnp.concatenate(losses)
+
+    def evaluate(self, tiid, tood):
+        parts = [self.evaluate_fn(jax.device_put(p), tiid, tood)
+                 for p, _ in self.state]
+        return tuple(jnp.concatenate(x) for x in zip(*parts))
+
+
 def replay(cfg: dict, traffic: dict, exp: Experiment, seed: int,
-           rounds: int, eval_rounds: List[int], dtype=jnp.float32
+           rounds: int, eval_rounds: List[int], dtype=jnp.float32,
+           budget: Optional[int] = None
            ) -> Dict[int, Dict[str, np.ndarray]]:
     """{evaluated round: {"train_loss", "iid_acc", "ood_acc"}: (n,) each}
-    over ``rounds`` rounds from the seed's initial weights."""
-    round_fn, evaluate = programs(cfg, traffic, dtype)
-    params, opt = initial_state(cfg, len(exp.parts), seed, dtype)
-    bank_x, bank_y = _bank(exp.parts)
+    over ``rounds`` rounds from the seed's initial weights. The nodes train
+    in blocks of ``block_size`` nodes; ``budget`` (bytes) stands in for
+    the device's memory in tests."""
+    progs = programs(cfg, traffic, dtype)
+    n = len(exp.parts)
+    bank = _bank(exp.parts)
+    size = block_size(n, node_bytes(cfg, dtype), budget)
+    nodes = (_AllNodes(progs, cfg, n, seed, dtype, bank) if size >= n
+             else _Blocks(progs, cfg, n, seed, dtype, bank, size))
     tiid = rmodels.batch_dtype(jax.tree.map(jnp.asarray, exp.test_iid), dtype)
     tood = rmodels.batch_dtype(jax.tree.map(jnp.asarray, exp.test_ood), dtype)
     out = {}
     for r in range(rounds):
         idx = rdata.round_order(exp.parts, seed, r, exp.steps,
                                 traffic["batch"], traffic["local_epochs"])
-        params, opt, losses = round_fn(params, opt, jnp.asarray(idx, jnp.int32),
-                                       bank_x, bank_y,
-                                       jnp.asarray(exp.coeffs(r), jnp.float32))
+        losses = nodes.round(jnp.asarray(idx, jnp.int32),
+                             jnp.asarray(exp.coeffs(r), jnp.float32))
         if r in eval_rounds:
-            iid, ood = evaluate(params, tiid, tood)
+            iid, ood = nodes.evaluate(tiid, tood)
             out[r] = {"train_loss": np.asarray(losses, np.float64),
                       "iid_acc": np.asarray(iid, np.float64),
                       "ood_acc": np.asarray(ood, np.float64)}

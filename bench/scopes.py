@@ -23,15 +23,23 @@ The scopes reach the trace only from an executable compiled from a
 program that has them: JAX's persistent cache leaves op metadata out of
 its key (``jax_compilation_cache_include_metadata_in_key`` is off), so
 an executable that a checkout without the scopes put in a shared cache
-is served to one with them, and its trace shows no scope.
+is served to one with them, and its trace shows no scope; the readers
+of scope time then find nothing to read.
 
-Self time. Operations nest on the line (a ``while`` covers its body's
-operations). At each instant of a chip's busy time the innermost covering
-operation (the latest to start; of those, the first to end) owns the
-time, and the innermost scope of its path owns it in turn, or
-``unscoped``. So the scopes' self times and ``unscoped`` sum to the busy
-time, and are averaged over the chips as ``bench.tracefile`` averages
-busy time.
+Scope names are open: the sweep's own (``SCOPES``) and those that a
+configuration's model file, ``bench/models/<model>.py``, declares with
+``scopes(cfg)`` (``declared``), such as the expert layer of a model that
+has one.
+
+Self and inclusive time. Operations nest on the line (a ``while`` covers
+its body's operations). At each instant of a chip's busy time the
+innermost covering operation (the latest to start; of those, the first
+to end) owns the time. The innermost scope of its path owns it as self
+time, or ``unscoped``; every scope of its path owns it as inclusive time.
+So the scopes' self times and ``unscoped`` sum to the busy time, and a
+scope nested in another (a model's expert layer inside ``local_train``)
+takes self time from it but leaves its inclusive time as it was. Both are
+averaged over the chips as ``bench.tracefile`` averages busy time.
 
 Spans. The program's host spans (``repro.*``, ``jax.profiler
 .TraceAnnotation``) lie on the host's Python line beside the harness's
@@ -45,9 +53,9 @@ import heapq
 import re
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
-from bench import tracefile
+from bench import spec, tracefile
 
-#: the program's scope names (``repro.core.decentralized``)
+#: the sweep's scope names (``repro.core.decentralized``)
 SCOPES = ("local_train", "mix", "coeffs", "eval", "batch_gather",
           "analytics")
 SPAN_PREFIX = "repro."
@@ -193,6 +201,14 @@ def innermost(events: Sequence[Labeled], lo: float, hi: float
     return out
 
 
+def declared(cfg: dict) -> Tuple[str, ...]:
+    """The scope names of a configuration's program: ``SCOPES`` and those
+    its model file declares with ``scopes(cfg)``."""
+    model = spec.model(cfg)
+    extra = tuple(model.scopes(cfg)) if hasattr(model, "scopes") else ()
+    return SCOPES + tuple(s for s in extra if s not in SCOPES)
+
+
 def named_scope(part: str, scopes: Sequence[str] = SCOPES
                 ) -> Optional[str]:
     """The scope one path component names, with any transform around it
@@ -204,25 +220,30 @@ def named_scope(part: str, scopes: Sequence[str] = SCOPES
         part = m.group(1)
 
 
-def scope_of(path: str, scopes: Sequence[str] = SCOPES) -> str:
-    """The innermost scope named in an op path, or ``unscoped``. The first
+def scopes_of(path: str, scopes: Sequence[str] = SCOPES) -> List[str]:
+    """The scopes named in an op path, outermost first. The first
     component names the program (``jit(f)``), or alone an argument (a
     copy of argument ``coeffs`` has the path ``coeffs``): never a scope."""
-    for part in reversed(path.rstrip(":").split("/")[1:]):
-        scope = named_scope(part, scopes)
-        if scope is not None:
-            return scope
-    return UNSCOPED
+    found = (named_scope(p, scopes) for p in path.rstrip(":").split("/")[1:])
+    return [s for s in found if s is not None]
 
 
-def device_ops(path: str) -> Dict[str, List[Labeled]]:
+def scope_of(path: str, scopes: Sequence[str] = SCOPES) -> str:
+    """The innermost scope named in an op path, or ``unscoped``."""
+    found = scopes_of(path, scopes)
+    return found[-1] if found else UNSCOPED
+
+
+def device_ops(path: str, data=None) -> Dict[str, List[Labeled]]:
     """device plane name -> [(start ns, end ns, ``tf_op``)] of its
-    ``XLA Ops`` line (``""`` where an event's metadata has no ``tf_op``)."""
+    ``XLA Ops`` line (``""`` where an event's metadata has no ``tf_op``).
+    ``data`` is the trace's ``jax.profiler.ProfileData`` where it is
+    already read."""
     from jax.profiler import ProfileData
 
     tf_ops = read_tf_ops(path)
     out: Dict[str, List[Labeled]] = {}
-    for plane in ProfileData.from_file(path).planes:
+    for plane in (data or ProfileData.from_file(path)).planes:
         if plane.name not in tf_ops:
             continue
         names = tf_ops[plane.name]
@@ -233,18 +254,46 @@ def device_ops(path: str) -> Dict[str, List[Labeled]]:
     return out
 
 
-def scope_seconds(ops: Dict[str, List[Labeled]], window: tracefile.Interval
-                  ) -> Dict[str, float]:
-    """Self seconds of each scope and ``unscoped`` inside the window,
-    averaged over the chips; they sum to the busy seconds."""
+def path_seconds(ops: Dict[str, List[Labeled]], window: tracefile.Interval
+                 ) -> Dict[str, float]:
+    """Busy seconds inside the window by the path of the innermost op,
+    averaged over the chips."""
     if not ops:
         raise ValueError("the trace holds no device plane")
     out: Dict[str, float] = {}
     for events in ops.values():
         for a, b, path in innermost(events, *window):
-            scope = scope_of(path)
-            out[scope] = out.get(scope, 0.0) + (b - a) * 1e-9 / len(ops)
+            out[path] = out.get(path, 0.0) + (b - a) * 1e-9 / len(ops)
     return out
+
+
+def self_seconds(paths: Dict[str, float], scopes: Sequence[str] = SCOPES
+                 ) -> Dict[str, float]:
+    """Self seconds of each scope and ``unscoped``, from ``path_seconds``;
+    they sum to the busy seconds."""
+    out: Dict[str, float] = {}
+    for path, secs in paths.items():
+        scope = scope_of(path, scopes)
+        out[scope] = out.get(scope, 0.0) + secs
+    return out
+
+
+def inclusive_seconds(paths: Dict[str, float], scopes: Sequence[str] = SCOPES
+                      ) -> Dict[str, float]:
+    """Inclusive seconds of each scope: the busy seconds whose op path
+    holds it, at any depth."""
+    out: Dict[str, float] = {}
+    for path, secs in paths.items():
+        for scope in set(scopes_of(path, scopes)):
+            out[scope] = out.get(scope, 0.0) + secs
+    return out
+
+
+def scope_seconds(ops: Dict[str, List[Labeled]], window: tracefile.Interval,
+                  scopes: Sequence[str] = SCOPES) -> Dict[str, float]:
+    """Self seconds of each scope and ``unscoped`` inside the window,
+    averaged over the chips; they sum to the busy seconds."""
+    return self_seconds(path_seconds(ops, window), scopes)
 
 
 # -- host spans --------------------------------------------------------
@@ -287,21 +336,33 @@ def idle_by_span(host: Sequence[Labeled], chip: Sequence[Labeled],
 
 
 def split(trace: tracefile.Trace, ops: Dict[str, List[Labeled]],
-          window: tracefile.Interval) -> dict:
+          window: tracefile.Interval, scopes: Sequence[str] = SCOPES) -> dict:
     """What a trace says of the program's scopes and spans inside the
-    window: busy seconds, scope self seconds (``ops`` from
-    :func:`device_ops`), span seconds, the window's calls (the harness's
-    ``bench.call.<i>`` spans) and the first chip's idle seconds by program
-    span."""
-    scopes = scope_seconds(ops, window)
+    window: busy seconds, scope self and inclusive seconds (``ops`` from
+    :func:`device_ops`, scope names ``scopes``), span seconds, the
+    window's calls (the harness's ``bench.call.<i>`` spans) and the first
+    chip's idle seconds by program span."""
+    paths = path_seconds(ops, window)
+    self_s = self_seconds(paths, scopes)
     lo, hi = window
     calls = sum(1 for a, b, n in trace.host
                 if n.startswith("bench.call.") and lo <= a and b <= hi)
     return {
         "window_s": (hi - lo) * 1e-9,
-        "busy_s": sum(scopes.values()),
-        "scope_s": scopes,
+        "busy_s": sum(self_s.values()),
+        "scope_s": self_s,
+        "inclusive_s": inclusive_seconds(paths, scopes),
         "span_s": span_seconds(trace.host, window),
         "calls": calls,
         "idle_by_span_s": idle_by_span(trace.host, ops[min(ops)], window),
     }
+
+
+def share(ctx: dict, scope: str) -> Optional[float]:
+    """A metric reader's share of the traced window's busy time that ops
+    under ``scope`` (inclusive) took, in percent; None without a trace or
+    where no op carries the scope."""
+    if ctx["scopes"] is None or ctx["trace"]["busy_s"] <= 0:
+        return None
+    secs = ctx["scopes"]["inclusive"].get(scope)
+    return None if not secs else 100.0 * secs / ctx["trace"]["busy_s"]

@@ -6,13 +6,13 @@ program's device scopes and host spans.
 
 Sets up as ``bench/run.py`` does (the cell's grid and one warm-up call),
 traces one window of calls with the harness's spans, and prints one JSON
-line: busy seconds, device self seconds by named scope, host seconds by
-program span, the first chip's idle seconds by the innermost program
-span, JAX's jaxpr traces and backend compiles in the window (the
-harness's ``Clock``; a window that compiled is not a steady one), and
-per call the numbers that ``local_train_share``, ``eval_share``,
-``entry_prep_s``, ``engine_host_s`` and ``traces_per_call`` would report
-(``bench/scopes.py``). Exits 2 without a TPU.
+line: busy seconds, device self and inclusive seconds by named scope,
+host seconds by program span, the first chip's idle seconds by the
+innermost program span, JAX's jaxpr traces and backend compiles in the
+window (the harness's ``Clock``; a window that compiled is not a steady
+one), and under ``per_layer`` what the cell's per-layer readers
+(``bench/metrics``) take from the same ``ctx`` as the harness gives them,
+those that need the check (``step_mfu``) left out. Exits 2 without a TPU.
 """
 import time
 
@@ -25,21 +25,6 @@ import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
-TRACES = "/jax/core/compile/jaxpr_trace_duration"
-
-
-def per_call(split: dict, traces: int) -> dict:
-    """The five per-call numbers of a window's split."""
-    calls, busy = split["calls"], split["busy_s"]
-    scope, span = split["scope_s"], split["span_s"]
-    return {
-        "local_train_share": 100.0 * scope.get("local_train", 0.0) / busy,
-        "eval_share": 100.0 * scope.get("eval", 0.0) / busy,
-        "entry_prep_s": span.get("repro.sweep.prep", 0.0) / calls,
-        "engine_host_s": (span.get("repro.engine.prepare", 0.0)
-                          + span.get("repro.engine.chunk", 0.0)) / calls,
-        "traces_per_call": traces / calls,
-    }
 
 
 def main(argv) -> int:
@@ -53,7 +38,7 @@ def main(argv) -> int:
 
     import jax
 
-    from bench import harness, scopes, spec, tracefile
+    from bench import harness, scopes, spec
 
     cell = spec.Cell.load(args.workload)
     harness.enable_cache()
@@ -73,16 +58,14 @@ def main(argv) -> int:
     clock.phase = "after"
     jax.profiler.stop_trace()
     t0 = time.perf_counter()
-    path = tracefile.find_xplane(str(harness.TRACE_DIR))
-    trace = tracefile.Trace.load(path)
-    window = trace.span("bench.window")
-    out = scopes.split(trace, scopes.device_ops(path), window)
-    reduced = tracefile.reduce(trace, window)
-    shutil.rmtree(harness.TRACE_DIR, ignore_errors=True)
-    traces = clock.counts.get("window", {}).get(TRACES, 0)
-    out.update(per_call(out, traces),
+    reduced, out = harness.read_trace(scopes.declared(cell.config))
+    ctx = dict(harness.window_context(cell, win, clock),
+               **harness.trace_context(reduced, out), chips=cell.chips,
+               peak=None, flops_per_node_round=None)
+    out.update(per_layer=harness.read_metrics(cell, ctx, trace=True),
                tracefile_busy_s=reduced["busy_s"], device=dev,
-               setup_s=setup_s, call_s=win["call_s"], window_traces=traces,
+               setup_s=setup_s, call_s=win["call_s"],
+               window_counts=ctx["counts"],
                window_compiles=clock.compiles("window"),
                reduce_s=time.perf_counter() - t0)
     print(json.dumps(out), flush=True)

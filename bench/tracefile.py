@@ -85,7 +85,11 @@ class Trace:
     def load(cls, path: str) -> "Trace":
         from jax.profiler import ProfileData
 
-        data = ProfileData.from_file(path)
+        return cls.of(ProfileData.from_file(path))
+
+    @classmethod
+    def of(cls, data) -> "Trace":
+        """The trace of a ``jax.profiler.ProfileData`` already read."""
         ops: Dict[str, List[Tuple[float, float, str]]] = {}
         host: List[Tuple[float, float, str]] = []
         for plane in data.planes:

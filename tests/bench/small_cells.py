@@ -100,12 +100,12 @@ def reference_once() -> Iterator[None]:
 
     real, memo = replay.replay, {}
 
-    def once(cfg, traffic, exp, seed, rounds, eval_rounds, dtype):
-        key = (json.dumps([cfg, traffic], sort_keys=True), exp.strategy,
+    def once(cfg, traffic, exp, seed, rounds, eval_rounds, dtype, **kw):
+        key = (json.dumps([cfg, traffic, kw], sort_keys=True), exp.strategy,
                seed, rounds, tuple(eval_rounds), str(dtype))
         if key not in memo:
             memo[key] = real(cfg, traffic, exp, seed, rounds, eval_rounds,
-                             dtype)
+                             dtype, **kw)
         return memo[key]
 
     replay.replay = once
@@ -147,3 +147,29 @@ def control_gaps(cell: spec.Cell, seed: int) -> dict:
 def exceeds(numbers: dict, limits: dict) -> list:
     """Names of the numbers above their limits."""
     return [k for k, v in numbers.items() if k in limits and v > limits[k]]
+
+
+#: the traffic each configuration's reference replays in the tests
+REPLAY_TRAFFIC = {"ffn3": "fig4grid.mesh4", "gpt2s-1l": "ba33.fedavg.r1",
+                  "vgg16": "ba33.fedavg.r1"}
+
+
+def replay_small(config: str, seed: int, dtype_name: str = "float32",
+                 **kw) -> dict:
+    """The reference's outputs, {evaluated round: {name: (n,)}}, of the
+    configuration on its test-size traffic (``REPLAY_TRAFFIC``, cut as its
+    cells are), one ``degree`` experiment from ``seed``; ``kw`` goes to
+    ``replay.replay``."""
+    import jax.numpy as jnp
+
+    from bench.checks import sync_mean
+    from bench.reference import replay
+
+    cell = cut_to_size(cell_from_files(config, config, REPLAY_TRAFFIC[config],
+                                       1, {}))
+    t = cell.traffic
+    rounds = t["rounds_per_call"]
+    exp = replay.build(cell.config, t, "degree", seed)
+    return replay.replay(cell.config, t, exp, seed, rounds,
+                         sync_mean.eval_rounds(rounds, t["eval_every"]),
+                         getattr(jnp, dtype_name), **kw)

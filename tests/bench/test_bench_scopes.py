@@ -8,11 +8,14 @@ matrix products and then an ``eval``-scoped ``lax.map`` of them, each run
 to completion and timed by the host (``data/scoped.json``).
 """
 import json
+import shutil
 from pathlib import Path
 
 import pytest
 
-from bench import scopes, spec, tracefile
+from bench import harness, scopes, spec, tracefile
+
+TRACES = "/jax/core/compile/jaxpr_trace_duration"
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -122,19 +125,36 @@ def test_idle_by_span_names_the_innermost_program_span():
     assert sum(out.values()) == pytest.approx(idle)
 
 
-def _split_module():
-    return spec.load_module(spec.BENCH / "split.py")
+PER_CALL = ("local_train_share", "eval_share", "entry_prep_s",
+            "engine_host_s", "traces_per_call")
+
+
+def _read(ctx: dict) -> dict:
+    """The five per-call metrics as the harness reads them from ``ctx``."""
+    cell = spec.Cell.load("gpt2s-1l.ba33.fedavg")
+    metrics = harness.read_metrics(cell, ctx, trace=True)
+    return {k: metrics[k]["value"] for k in PER_CALL if k in metrics}
+
+
+def _ctx(reduced: dict, split: dict, calls: int, traces: int) -> dict:
+    return dict(harness.trace_context(reduced, split), calls=calls,
+                counts={TRACES: traces}, chips=1, peak=None,
+                flops_per_node_round=None, node_rounds=66, window_s=40.0)
 
 
 def test_per_call_numbers_of_a_split():
     split = {"calls": 2, "busy_s": 40.0,
              "scope_s": {"local_train": 37.0, "eval": 2.5, "unscoped": 0.5},
+             "inclusive_s": {"local_train": 37.0, "eval": 2.5},
              "span_s": {"repro.sweep.prep": 1.8, "repro.engine.prepare": 0.4,
                         "repro.engine.chunk": 9.0}}
-    out = _split_module().per_call(split, traces=1230)
-    assert out == pytest.approx({
+    ctx = _ctx({"busy_s": 40.0, "idle_share": 0.1}, split, 2, 1230)
+    assert _read(ctx) == pytest.approx({
         "local_train_share": 92.5, "eval_share": 6.25, "entry_prep_s": 0.9,
         "engine_host_s": 4.7, "traces_per_call": 615.0})
+    # untraced, the readers of the trace find nothing to read
+    untraced = dict(ctx, **harness.trace_context(None, None))
+    assert _read(untraced) == {"traces_per_call": 615.0}
 
 
 @pytest.fixture(scope="module")
@@ -158,3 +178,91 @@ def test_recorded_scopes_match_the_host_timing(recorded):
         100 * host["train_s"] / total, abs=2.0)
     assert share["eval"] == pytest.approx(100 * host["eval_s"] / total,
                                           abs=2.0)
+
+
+def test_harness_ctx_holds_the_recorded_split(recorded, tmp_path, monkeypatch):
+    """The harness reads the traced window once, removes it, and gives the
+    readers the scope times and spans that ``scopes.split`` gives; the
+    readers' shares are inclusive scope time over the busy time."""
+    path, _ = recorded
+    traced = tmp_path / "trace" / "plugins" / "profile" / "1"
+    traced.mkdir(parents=True)
+    shutil.copy(path, traced / "host.xplane.pb")
+    monkeypatch.setattr(harness, "TRACE_DIR", tmp_path / "trace")
+    reduced, split = harness.read_trace(scopes.SCOPES)
+    assert not (tmp_path / "trace").exists()
+    trace = tracefile.Trace.load(path)
+    window = trace.span("bench.window")
+    want = scopes.split(trace, scopes.device_ops(path), window)
+    assert reduced == tracefile.reduce(trace, window)
+    assert split == want
+    ctx = _ctx(reduced, split, want["calls"], 30)
+    assert ctx["scopes"] == {"self": want["scope_s"],
+                             "inclusive": want["inclusive_s"]}
+    assert ctx["spans"] == want["span_s"] == {}
+    busy = reduced["busy_s"]
+    # nothing nests in the recording's two scopes
+    assert want["inclusive_s"] == {k: v for k, v in want["scope_s"].items()
+                                   if k != "unscoped"}
+    # no program spans in the recording: their readers find nothing
+    assert _read(ctx) == pytest.approx({
+        "local_train_share": 100 * want["inclusive_s"]["local_train"] / busy,
+        "eval_share": 100 * want["inclusive_s"]["eval"] / busy,
+        "traces_per_call": 15.0})
+
+
+LOOP = "jit(f)/vmap()/while"
+TRAIN = "jit(f)/vmap()/while/body/closed_call/local_train/dot_general:"
+EVAL = "jit(f)/vmap()/while/body/closed_call/eval/cond/dot_general:"
+
+
+def _synthetic(ops: dict) -> tracefile.Trace:
+    return tracefile.Trace({k: [(a, b, "op") for a, b, _ in v]
+                            for k, v in ops.items()}, HOST)
+
+
+def test_readers_of_a_synthetic_split():
+    """Calls and program spans the recording lacks, on synthetic events:
+    two calls, each with its spans (``HOST``)."""
+    ops = {"/device:TPU:0": [(100, 700, LOOP), (150, 300, TRAIN),
+                             (320, 520, TRAIN), (560, 640, EVAL)]}
+    trace = _synthetic(ops)
+    split = scopes.split(trace, ops, (0, 1000))
+    reduced = tracefile.reduce(trace, (0, 1000))
+    assert split["calls"] == 2 and reduced["busy_s"] == pytest.approx(600e-9)
+    assert _read(_ctx(reduced, split, split["calls"], 1230)) == pytest.approx({
+        "local_train_share": 100 * 350 / 600, "eval_share": 100 * 80 / 600,
+        "entry_prep_s": 380e-9 / 2,
+        "engine_host_s": (60 + 190 + 290) * 1e-9 / 2,
+        "traces_per_call": 615.0})
+
+
+def test_a_declared_scope_nested_in_local_train():
+    """A scope that only a model file declares (``expert``) owns its ops'
+    self time; ``local_train`` keeps its inclusive time."""
+    expert = ("jit(f)/vmap()/while/body/closed_call/local_train/"
+              "vmap(expert)/dot_general:")
+    ops = {"/device:TPU:0": [(100, 700, LOOP), (150, 300, TRAIN),
+                             (320, 520, expert), (560, 640, EVAL)]}
+    trace = _synthetic(ops)
+    plain = scopes.split(trace, ops, (0, 1000))
+    named = scopes.split(trace, ops, (0, 1000),
+                         scopes.SCOPES + ("expert",))
+    assert "expert" not in plain["scope_s"]
+    assert plain["scope_s"]["local_train"] == pytest.approx(350e-9)
+    assert named["scope_s"]["expert"] == pytest.approx(200e-9)
+    assert named["scope_s"]["local_train"] == pytest.approx(150e-9)
+    assert named["inclusive_s"]["expert"] == pytest.approx(200e-9)
+    for split in (plain, named):
+        assert split["inclusive_s"]["local_train"] == pytest.approx(350e-9)
+        assert split["busy_s"] == pytest.approx(600e-9)
+    assert scopes.scopes_of(expert, scopes.SCOPES + ("expert",)) == [
+        "local_train", "expert"]
+
+
+@pytest.mark.parametrize("name", sorted(p.stem for p in (
+    spec.BENCH / "configs").glob("*.json")))
+def test_declared_scopes_of_the_benchmark_models(name):
+    """None of the benchmark's own models declares a scope of its own."""
+    cfg = json.loads((spec.BENCH / "configs" / f"{name}.json").read_text())
+    assert scopes.declared(cfg) == scopes.SCOPES
